@@ -412,6 +412,7 @@ def main(argv=None):
     import jax
 
     from benchmarks import roofline
+    from repro.jax_setup import configure_jax
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--cells", default="smoke",
@@ -420,6 +421,7 @@ def main(argv=None):
     ap.add_argument("--update", action="store_true",
                     help="write BENCH_matrix.json (requires --cells all)")
     args = ap.parse_args(argv)
+    configure_jax()
 
     if args.cells == "all":
         cells = CELLS

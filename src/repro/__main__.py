@@ -31,6 +31,10 @@ def main():
     mod_name = COMMANDS[cmd][0]
     import importlib
 
+    from repro.jax_setup import configure_jax
+
+    configure_jax()
+
     mod = importlib.import_module(mod_name)
     sys.argv = [f"python -m {mod_name}"] + argv[1:]
     mod.main()

@@ -17,6 +17,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import rounding as R
 
@@ -33,7 +34,7 @@ MAX_POS = (2.0 ** 15 * 1.5) * 4.0 * 1.75   # = 2^18 * 1.3125  (Table II)
 MIN_POS = 2.0 ** -48 * 0.25                # = 2^-50           (Table II)
 INTRA_MAX = 7.0                            # 2^(1+1) * 1.75 (Alg. 1 line 8)
 
-_RECIP7_BF16 = float(jnp.asarray(1.0 / 7.0, jnp.bfloat16))  # (1/7)_BF16
+_RECIP7_BF16 = float(np.asarray(1.0 / 7.0, jnp.bfloat16))  # (1/7)_BF16
 
 
 class HiF4Groups(NamedTuple):
@@ -55,16 +56,11 @@ class HiF4Packed(NamedTuple):
 def quantize_groups(v: jnp.ndarray) -> HiF4Groups:
     """Algorithm 1: convert (..., 64) bf16/f32 values to HiF4 components.
 
-    f32 inputs use the explicitly bf16-emulated path (every bf16 hardware
-    rounding simulated with round_bf16). bf16 inputs take the NATIVE path:
-    arithmetic runs in bf16 directly — bf16 multiplies round exactly like
-    the simulated round_bf16(product), and every intermediate value on the
-    S1P2/E6M2 grids is exactly bf16-representable, so the two paths agree
-    BITWISE (property-tested) while the native one halves the HBM traffic
-    of in-graph activation quantization.
+    Runs in f32 with every bf16 hardware rounding made explicit
+    (``round_bf16``), for bf16 inputs too: plain bf16 arithmetic would
+    round the same way op by op, but under ``jit`` XLA may fuse bf16 ops
+    in excess precision and skip those roundings.
     """
-    if v.dtype == jnp.bfloat16:
-        return _quantize_groups_bf16(v)
     v = v.astype(jnp.float32)
     av = jnp.abs(v)
     lead = v.shape[:-1]
@@ -94,48 +90,16 @@ def quantize_groups(v: jnp.ndarray) -> HiF4Groups:
     return HiF4Groups(e6m2=e6m2, e1_8=e1_8, e1_16=e1_16, s1p2=s1p2)
 
 
-def _quantize_groups_bf16(v: jnp.ndarray) -> HiF4Groups:
-    """Native-bf16 Algorithm 1 (the big (..., 64) buffers never touch f32).
-
-    Per-group metadata (1/64 of the data) still routes through f32 for the
-    E6M2 grid arithmetic — that part is cheap.
-    """
-    bf = jnp.bfloat16
-    av = jnp.abs(v)
-    lead = v.shape[:-1]
-    v16 = jnp.max(av.reshape(lead + (16, 4)), axis=-1)          # bf16, exact
-    v8 = jnp.max(v16.reshape(lead + (8, 2)), axis=-1)
-    vmax = jnp.max(v8, axis=-1)
-
-    sf = vmax * bf(_RECIP7_BF16)                                # bf16 RNE = line 8
-    e6m2 = R.round_e6m2(sf.astype(jnp.float32))                 # small, f32
-    rec_f32 = R.e6m2_reciprocal_bf16(e6m2)
-    rec = rec_f32.astype(bf)                                    # exactly bf16
-
-    e1_8 = ((v8 * rec[..., None]) > bf(4.0)).astype(jnp.int32)  # line 11
-    shift2 = jnp.repeat(e1_8, 2, axis=-1)
-    t16 = (v16 * rec[..., None]) * jnp.exp2(-shift2).astype(bf)
-    e1_16 = (t16 >= bf(2.0)).astype(jnp.int32)                  # line 13
-
-    shift8 = jnp.repeat(e1_8, 8, axis=-1)
-    shift4 = jnp.repeat(e1_16, 4, axis=-1)
-    scaled = (v * rec[..., None]) * jnp.exp2(-(shift8 + shift4)).astype(bf)
-    # S1P2 rounding: x4, RNE to int in [-7, 7], /4 — all exact in bf16
-    q = jnp.clip(jnp.round(scaled * bf(4.0)), -7.0, 7.0)
-    s1p2 = q * bf(0.25)                                         # stays bf16
-    return HiF4Groups(e6m2=e6m2, e1_8=e1_8, e1_16=e1_16, s1p2=s1p2)
-
-
 def dequantize_groups(g: HiF4Groups) -> jnp.ndarray:
     """Equation 2: reconstruct (..., 64) values.
 
     Computes in the s1p2 dtype: the product E6M2 * 2^shift * S1P2 carries
-    at most 2+3 significant bits, so it is EXACT in bf16 as well as f32 —
-    the native-bf16 path keeps the big buffers bf16 end to end.
+    at most 2+3 significant bits, so it is EXACT in bf16 as well as f32.
     """
     dt = g.s1p2.dtype
     shift = jnp.repeat(g.e1_8, 8, axis=-1) + jnp.repeat(g.e1_16, 4, axis=-1)
-    scale = g.e6m2.astype(dt)[..., None] * jnp.exp2(shift).astype(dt)
+    scale = g.e6m2.astype(dt)[..., None] * jnp.ldexp(
+        jnp.ones((), dt), shift)
     return scale * g.s1p2
 
 
@@ -186,6 +150,11 @@ def to_absorbed_int(g: HiF4Groups) -> tuple[jnp.ndarray, jnp.ndarray]:
 # those buffers to the absorbed-shift int8 operand of paper §III.B.  They
 # are pure jnp on whatever tile they are given — the same code runs inside
 # a Pallas kernel on VMEM refs and in the XLA twin of the fused matmul.
+# They are written in the forms the TPU kernel compiler (Mosaic) accepts:
+# sub-word and unsigned integers widen to int32 before any arithmetic,
+# unsigned words are bitcast to int32 and shifted logically, index
+# vectors are 2-D+ iotas, and per-group values broadcast over a new
+# sublane axis instead of ``jnp.repeat``.
 
 
 def expand_codes_km(codes_km: jnp.ndarray) -> jnp.ndarray:
@@ -193,12 +162,36 @@ def expand_codes_km(codes_km: jnp.ndarray) -> jnp.ndarray:
 
     Low nibble is the even contraction row, high nibble the odd one; the
     4-bit code is sign<<3 | quarters (rounding.encode_s1p2)."""
-    lo = (codes_km & 0xF).astype(jnp.int32)
-    hi = (codes_km >> 4).astype(jnp.int32)
+    c = codes_km.astype(jnp.int32)
     half, bn = codes_km.shape
-    c4 = jnp.stack([lo, hi], axis=1).reshape(half * 2, bn)
+    c4 = jnp.stack([c & 0xF, c >> 4], axis=1).reshape(half * 2, bn)
     mag = c4 & 0x7
-    return jnp.where((c4 >> 3) & 1, -mag, mag)
+    return jnp.where((c4 >> 3) & 1 == 1, -mag, mag)
+
+
+def _meta_shift_scale(meta_km: jnp.ndarray):
+    """(bg, bn) uint32 metadata -> (shift (bg, 64, bn) int32, scale
+    (bg, bn) f32): the grouped form :func:`expand_meta_km` flattens."""
+    bg, bn = meta_km.shape
+    m = jax.lax.bitcast_convert_type(meta_km, jnp.int32)
+    w8 = jax.lax.shift_right_logical(m, 16) & 0xFF   # E1_8 bits
+    w16 = m & 0xFFFF                                  # E1_16 bits
+    r = jax.lax.broadcasted_iota(jnp.int32, (bg, GROUP_SIZE, bn), 1)
+    shift = (((w8[:, None, :] >> (r // 8)) & 1)
+             + ((w16[:, None, :] >> (r // 4)) & 1))
+    code = jax.lax.shift_right_logical(m, 24)
+    # 2^eb built by exponent-field bitcast: jnp.exp2 is a polynomial
+    # approximation that is NOT exact across the E6M2 range (observed
+    # exp2(15) != 32768 on CPU), and the scale must stay on the exact
+    # power-of-two grid. eb in [-48, 15] is always a normal f32.
+    eb = (code >> 2) - 48
+    pow2 = jax.lax.bitcast_convert_type((eb + 127) << 23, jnp.float32)
+    m2 = (code & 0x3).astype(jnp.float32)
+    scale = pow2 * (1.0 + m2 * 0.25) * 0.25
+    # E6M2 0xFF is NaN (never produced by Algorithm 1, but corrupted bits
+    # must decode identically on every path — decode_e6m2 parity)
+    scale = jnp.where(code == 0xFF, jnp.nan, scale)
+    return shift, scale
 
 
 def expand_meta_km(meta_km: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -207,28 +200,10 @@ def expand_meta_km(meta_km: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     ``shift`` (bg*64, bn) int32 is the per-element micro-exponent sum
     E1_8 + E1_16; ``scale`` (bg, bn) f32 is the absorbed group scale
     E6M2 / 4 (bitwise identical to ``decode_e6m2(meta>>24) * 0.25`` but
-    written with exp2 on the small per-group tile only, no LUT)."""
-    bg, bn = meta_km.shape
-    w8 = meta_km >> 16                       # E1_8 bits in 23..16
-    w16 = meta_km                            # E1_16 bits in 15..0
-    r = jnp.arange(GROUP_SIZE, dtype=jnp.uint32)
-    s8 = ((w8[:, None, :] >> (r[None, :, None] // 8)) & 1).astype(jnp.int32)
-    s4 = ((w16[:, None, :] >> (r[None, :, None] // 4)) & 1).astype(jnp.int32)
-    shift = (s8 + s4).reshape(bg * GROUP_SIZE, bn)
-    code = meta_km >> 24
-    # 2^eb built by exponent-field bitcast: jnp.exp2 is a polynomial
-    # approximation that is NOT exact across the E6M2 range (observed
-    # exp2(15) != 32768 on CPU), and the scale must stay on the exact
-    # power-of-two grid. eb in [-48, 15] is always a normal f32.
-    eb = (code >> 2).astype(jnp.int32) - 48
-    pow2 = jax.lax.bitcast_convert_type(
-        ((eb + 127) << 23).astype(jnp.uint32), jnp.float32)
-    m2 = (code & 0x3).astype(jnp.float32)
-    scale = pow2 * (1.0 + m2 * 0.25) * 0.25
-    # E6M2 0xFF is NaN (never produced by Algorithm 1, but corrupted bits
-    # must decode identically on every path — decode_e6m2 parity)
-    scale = jnp.where(code == 0xFF, jnp.nan, scale)
-    return shift, scale
+    built by exponent bitcast on the small per-group tile only, no LUT)."""
+    shift, scale = _meta_shift_scale(meta_km)
+    bg, _, bn = shift.shape
+    return shift.reshape(bg * GROUP_SIZE, bn), scale
 
 
 def absorbed_int_km(codes_km: jnp.ndarray, meta_km: jnp.ndarray):
@@ -251,9 +226,11 @@ def dequantize_km(codes_km: jnp.ndarray, meta_km: jnp.ndarray,
     is exact in bf16 as well as f32 — and unlike the output-major
     dequantize it needs no final (N, K) -> (K, N) transpose and no
     per-element exp2 (shifts are integer left-shifts)."""
-    ints, scale = absorbed_int_km(codes_km, meta_km)
-    scale_k = jnp.repeat(scale, GROUP_SIZE, axis=0)
-    return (scale_k * ints.astype(jnp.float32)).astype(dtype)
+    shift, scale = _meta_shift_scale(meta_km)
+    bg, _, bn = shift.shape
+    quarters = expand_codes_km(codes_km).reshape(bg, GROUP_SIZE, bn)
+    vals = scale[:, None, :] * (quarters << shift).astype(jnp.float32)
+    return vals.reshape(bg * GROUP_SIZE, bn).astype(dtype)
 
 
 # ---------------------------------------------------------------------------

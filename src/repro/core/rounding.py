@@ -11,6 +11,7 @@ patterns for the packed-storage path.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # ---------------------------------------------------------------------------
@@ -19,8 +20,14 @@ import jax.numpy as jnp
 
 
 def round_bf16(x: jnp.ndarray) -> jnp.ndarray:
-    """Round float32 -> nearest bfloat16 (RNE), returned as float32."""
-    return x.astype(jnp.bfloat16).astype(jnp.float32)
+    """Round float32 -> nearest bfloat16 (RNE), returned as float32.
+
+    An explicit ``reduce_precision``: under ``jit`` XLA may keep a value in
+    excess precision across a bf16 round trip or between fused bf16 ops
+    (``xla_allow_excess_precision``), which moves Algorithm-1 decisions;
+    a precision reduction is never elided."""
+    return jax.lax.reduce_precision(x.astype(jnp.float32), exponent_bits=8,
+                                    mantissa_bits=7)
 
 
 def _binade_exponent(ax: jnp.ndarray) -> jnp.ndarray:

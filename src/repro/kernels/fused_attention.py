@@ -83,6 +83,9 @@ KV_GRID_AXIS = 2
 # softmax, bitwise).
 _KV_TILE = 256
 
+_LANE = 128        # lane tile: token-axis blocks are multiples or all of S
+_META_ROWS = 8     # sublane tile of the uint32 meta block (feature groups)
+
 
 def select_kv_block(seq: int, block_kv: Optional[int] = None) -> int:
     """Per-regime KV tile size: whole cache when it fits one tile
@@ -104,12 +107,22 @@ def select_kv_block(seq: int, block_kv: Optional[int] = None) -> int:
     return best
 
 
-def heads_per_block(d_head: int) -> int:
+def heads_per_block(d_head: int, n_kv_heads: Optional[int] = None) -> int:
     """KV heads per grid step so head blocks hold whole 64-groups.
 
     d_head % 64 == 0 -> 1; d_head = 32 -> 2; etc. (lcm(d_head, 64)/d_head).
+    With ``n_kv_heads`` given, the block the TPU compiler can tile: the
+    smallest multiple of that unit dividing the head count whose
+    (hb*d_head/64)-row meta block is a whole 8-row sublane tile, or all
+    heads (a full-extent block) when no such multiple exists.
     """
-    return math.lcm(d_head, 64) // d_head
+    unit = math.lcm(d_head, 64) // d_head
+    if n_kv_heads is None:
+        return unit
+    for hb in range(unit, n_kv_heads + 1, unit):
+        if n_kv_heads % hb == 0 and (hb * d_head // 64) % _META_ROWS == 0:
+            return hb
+    return n_kv_heads
 
 
 def kernel_compatible(k_cache: dict, n_kv_heads: int, d_head: int) -> bool:
@@ -125,29 +138,37 @@ def kernel_compatible(k_cache: dict, n_kv_heads: int, d_head: int) -> bool:
     )
 
 
-def _fused_decode_kernel(q_ref, len_ref, kc_ref, km_ref, vc_ref, vm_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, d_head: int,
-                         n_tiles: int, block_kv: int):
-    ki = pl.program_id(KV_GRID_AXIS)
+def _dma_width(seq: int, ck: int) -> int:
+    """Token columns one grid step DMAs: the smallest multiple of the
+    recurrence tile ``ck`` that is a whole number of 128-lane tiles and
+    divides ``seq``, else the whole cache (a full-extent block). The
+    kernel folds the ``width / ck`` recurrence tiles of a step one by one,
+    so the arithmetic is the twin's at ``block_kv=ck`` whatever the DMA
+    width."""
+    for w in range(ck, seq + 1, ck):
+        if w % _LANE == 0 and seq % w == 0:
+            return w
+    return seq
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    hb, rep, _ = q_ref.shape[1:]
-    q = q_ref[0]                                         # (hb, rep, D) bf16
+def _online_softmax_tile(q, kc, km, vc, vm, length, first_pos, m_ref, l_ref,
+                         acc_ref, *, d_head: int):
+    """Fold one KV tile into the normalized online-softmax state.
+
+    q (hb, rep, D) bf16; kc/vc (hb*D/2, ck) uint8 and km/vm (hb*D/64, ck)
+    uint32 packed columns of tokens first_pos .. first_pos+ck-1.
+    """
+    hb = q.shape[0]
+    ck = kc.shape[-1]
     # expand the 4.5-bit tile to bf16 K/V columns IN VMEM (K-major helpers)
-    kT = hif4.dequantize_km(kc_ref[0], km_ref[0]).reshape(hb, d_head, block_kv)
-    vT = hif4.dequantize_km(vc_ref[0], vm_ref[0]).reshape(hb, d_head, block_kv)
+    kT = hif4.dequantize_km(kc, km).reshape(hb, d_head, ck)
+    vT = hif4.dequantize_km(vc, vm).reshape(hb, d_head, ck)
     s = jax.lax.dot_general(
         q, kT, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ) / (d_head ** 0.5)                                  # (hb, rep, ck)
-    kp = ki * block_kv + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, block_kv), 2)
-    s = jnp.where(kp < len_ref[0, 0], s, NEG_INF)
+    kp = first_pos + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ck), 2)
+    s = jnp.where(kp < length, s, NEG_INF)
 
     m_prev = m_ref[..., :1]
     l_prev = l_ref[..., :1]
@@ -164,9 +185,39 @@ def _fused_decode_kernel(q_ref, len_ref, kc_ref, km_ref, vc_ref, vm_ref,
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(ki == n_tiles - 1)
+
+def _fused_decode_kernel(len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, d_head: int,
+                         n_steps: int, block_kv: int):
+    b = pl.program_id(0)
+    ki = pl.program_id(KV_GRID_AXIS)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0]                                         # (hb, rep, D) bf16
+    width = kc_ref.shape[-1]
+    for t in range(width // block_kv):                   # static sub-tiles
+        cols = slice(t * block_kv, (t + 1) * block_kv)
+        _online_softmax_tile(
+            q, kc_ref[0, :, cols], km_ref[0, :, cols], vc_ref[0, :, cols],
+            vm_ref[0, :, cols], len_ref[b], ki * width + t * block_kv,
+            m_ref, l_ref, acc_ref, d_head=d_head)
+
+    @pl.when(ki == n_steps - 1)
     def _fin():
         o_ref[0] = acc_ref[...]
+
+
+def _softmax_scratch(hb: int, rep: int, d_head: int):
+    return [
+        pltpu.VMEM((hb, rep, _LANE), jnp.float32),       # running max
+        pltpu.VMEM((hb, rep, _LANE), jnp.float32),       # running denom
+        pltpu.VMEM((hb, rep, d_head), jnp.float32),      # normalized acc
+    ]
 
 
 @functools.partial(
@@ -187,42 +238,42 @@ def fused_decode_attention(
     """Flash decode-attention straight off the 4.5-bit KV cache -> (B, H, D).
 
     Requires :func:`kernel_compatible` geometry (the engine routes
-    everything else to :func:`fused_decode_attention_xla`).
+    everything else to :func:`fused_decode_attention_xla`). ``length``
+    rides in SMEM as a scalar-prefetch operand.
     """
     B, H, D = q.shape
     assert D == d_head and kernel_compatible(k_cache, n_kv_heads, d_head)
     S = kvcache.seq_capacity(k_cache)
     rep = H // n_kv_heads
-    hb = heads_per_block(d_head)
+    hb = heads_per_block(d_head, n_kv_heads)
     ck = select_kv_block(S, block_kv)
-    n_tiles = S // ck
-    grid = (B, n_kv_heads // hb, n_tiles)
-    assert KV_GRID_AXIS == len(grid) - 1 and grid[KV_GRID_AXIS] == n_tiles
+    width = _dma_width(S, ck)
+    n_steps = S // width
+    grid = (B, n_kv_heads // hb, n_steps)
+    assert KV_GRID_AXIS == len(grid) - 1 and grid[KV_GRID_AXIS] == n_steps
 
     qf = q.reshape(B, n_kv_heads, rep, D)
-    len2 = length.astype(jnp.int32).reshape(B, 1)
     kernel = functools.partial(_fused_decode_kernel, d_head=d_head,
-                               n_tiles=n_tiles, block_kv=ck)
-    out = pl.pallas_call(
-        kernel,
+                               n_steps=n_steps, block_kv=ck)
+    codes = pl.BlockSpec((1, hb * D // 2, width), lambda b, h, k, ln: (b, h, k))
+    meta = pl.BlockSpec((1, hb * D // 64, width), lambda b, h, k, ln: (b, h, k))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, hb, rep, D), lambda b, h, k: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, k: (b, 0)),
-            pl.BlockSpec((1, hb * D // 2, ck), lambda b, h, k: (b, h, k)),
-            pl.BlockSpec((1, hb * D // 64, ck), lambda b, h, k: (b, h, k)),
-            pl.BlockSpec((1, hb * D // 2, ck), lambda b, h, k: (b, h, k)),
-            pl.BlockSpec((1, hb * D // 64, ck), lambda b, h, k: (b, h, k)),
+            pl.BlockSpec((1, hb, rep, D), lambda b, h, k, ln: (b, h, 0, 0)),
+            codes, meta, codes, meta,
         ],
-        out_specs=pl.BlockSpec((1, hb, rep, D), lambda b, h, k: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, hb, rep, D),
+                               lambda b, h, k, ln: (b, h, 0, 0)),
+        scratch_shapes=_softmax_scratch(hb, rep, D),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv_heads, rep, D), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((hb, rep, 128), jnp.float32),     # running max
-            pltpu.VMEM((hb, rep, 128), jnp.float32),     # running denom
-            pltpu.VMEM((hb, rep, D), jnp.float32),       # normalized acc
-        ],
         interpret=interpret,
-    )(qf, len2, k_cache["codes"], k_cache["meta"],
+    )(length.astype(jnp.int32), qf, k_cache["codes"], k_cache["meta"],
       v_cache["codes"], v_cache["meta"])
     return out.reshape(B, H, D).astype(q.dtype)
 
@@ -294,16 +345,17 @@ def fused_decode_attention_xla(
 # ---------------------------------------------------------------------------
 
 
-def _fused_paged_kernel(pt_ref, q_ref, len_ref, kc_ref, km_ref, vc_ref,
+def _fused_paged_kernel(pt_ref, len_ref, q_ref, kc_ref, km_ref, vc_ref,
                         vm_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                        d_head: int, n_tiles: int, block_kv: int):
-    # Scalar-prefetch kernels receive the prefetched operand first; the
+                        d_head: int, n_tiles: int):
+    # Scalar-prefetch kernels receive the prefetched operands first; the
     # page-table gather happened in the BlockSpec index maps, so the body
-    # is EXACTLY the contiguous kernel (same ops, same order -> bitwise).
+    # is EXACTLY one contiguous-kernel tile (same ops, same order ->
+    # bitwise).
     del pt_ref
-    _fused_decode_kernel(q_ref, len_ref, kc_ref, km_ref, vc_ref, vm_ref,
+    _fused_decode_kernel(len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref,
                          o_ref, m_ref, l_ref, acc_ref, d_head=d_head,
-                         n_tiles=n_tiles, block_kv=block_kv)
+                         n_steps=n_tiles, block_kv=kc_ref.shape[-1])
 
 
 @functools.partial(
@@ -322,59 +374,52 @@ def fused_paged_decode_attention(
 ) -> jax.Array:
     """Flash decode-attention off the PAGED 4.5-bit pool -> (B, H, D).
 
-    Grid (slot, head block, logical page): the page table rides in as a
-    scalar-prefetch operand and the KV BlockSpec index maps read
-    ``pages[b, k]`` to pick tile k's pool page, so each grid step DMAs
-    one page's packed payload — a gather walk over the table instead of
-    a contiguous token axis. The tile width IS the page size, logical
-    page index k supplies the positions for the length mask, and unused
-    trailing table entries (zeros -> the scratch page) are fully masked
-    exact no-ops, so the result is bitwise equal to the contiguous
-    kernel at ``block_kv=P`` on a page-multiple capacity.
+    Grid (slot, head block, logical page): the page table (flattened) and
+    the lengths ride in as scalar-prefetch operands and the KV BlockSpec
+    index maps read ``pages[b, k]`` to pick tile k's pool page, so each
+    grid step DMAs one page's packed payload — a gather walk over the
+    table instead of a contiguous token axis. The tile width IS the page
+    size, logical page index k supplies the positions for the length
+    mask, and unused trailing table entries (zeros -> the scratch page)
+    are fully masked exact no-ops, so the result is bitwise equal to the
+    contiguous kernel at ``block_kv=P`` on a page-multiple capacity.
     """
     B, H, D = q.shape
     assert D == d_head and kernel_compatible(k_pool, n_kv_heads, d_head)
     P = kvcache.pool_page_tokens(k_pool)
     n_tiles = pages.shape[1]
     rep = H // n_kv_heads
-    hb = heads_per_block(d_head)
+    hb = heads_per_block(d_head, n_kv_heads)
     grid = (B, n_kv_heads // hb, n_tiles)
     assert KV_GRID_AXIS == len(grid) - 1
 
     qf = q.reshape(B, n_kv_heads, rep, D)
-    len2 = length.astype(jnp.int32).reshape(B, 1)
     kernel = functools.partial(_fused_paged_kernel, d_head=d_head,
-                               n_tiles=n_tiles, block_kv=P)
+                               n_tiles=n_tiles)
+
+    def page(b, h, k, pt, ln):
+        return (pt[b * n_tiles + k], h, 0)
+
+    codes = pl.BlockSpec((1, hb * D // 2, P), page)
+    meta = pl.BlockSpec((1, hb * D // 64, P), page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, hb, rep, D), lambda b, h, k, pt: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, k, pt: (b, 0)),
-            pl.BlockSpec((1, hb * D // 2, P),
-                         lambda b, h, k, pt: (pt[b, k], h, 0)),
-            pl.BlockSpec((1, hb * D // 64, P),
-                         lambda b, h, k, pt: (pt[b, k], h, 0)),
-            pl.BlockSpec((1, hb * D // 2, P),
-                         lambda b, h, k, pt: (pt[b, k], h, 0)),
-            pl.BlockSpec((1, hb * D // 64, P),
-                         lambda b, h, k, pt: (pt[b, k], h, 0)),
+            pl.BlockSpec((1, hb, rep, D), lambda b, h, k, pt, ln: (b, h, 0, 0)),
+            codes, meta, codes, meta,
         ],
         out_specs=pl.BlockSpec((1, hb, rep, D),
-                               lambda b, h, k, pt: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hb, rep, 128), jnp.float32),     # running max
-            pltpu.VMEM((hb, rep, 128), jnp.float32),     # running denom
-            pltpu.VMEM((hb, rep, D), jnp.float32),       # normalized acc
-        ],
+                               lambda b, h, k, pt, ln: (b, h, 0, 0)),
+        scratch_shapes=_softmax_scratch(hb, rep, D),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv_heads, rep, D), jnp.float32),
         interpret=interpret,
-    )(pages.astype(jnp.int32), qf, len2, k_pool["codes"], k_pool["meta"],
-      v_pool["codes"], v_pool["meta"])
+    )(pages.astype(jnp.int32).reshape(-1), length.astype(jnp.int32), qf,
+      k_pool["codes"], k_pool["meta"], v_pool["codes"], v_pool["meta"])
     return out.reshape(B, H, D).astype(q.dtype)
 
 

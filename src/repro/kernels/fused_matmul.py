@@ -41,9 +41,10 @@ from repro.core import hif4
 from repro.kernels.bfp_matmul import (
     GROUP,
     K_GRID_AXIS,
-    _fit,
     _tile_group_dot,
-    select_block_sizes,
+    group_major,
+    matmul_call,
+    resolve_blocks,
 )
 
 
@@ -84,27 +85,11 @@ def fused_packed_matmul(
     half, N = codes_km.shape
     assert 2 * half == K and K % GROUP == 0, (a_ints.shape, codes_km.shape)
     assert meta_km.shape == (K // GROUP, N), meta_km.shape
-    abm, abn, abk = select_block_sizes(M, N, K)
-    bm = _fit(M, min(block_m, M), 1) if block_m else abm
-    bn = _fit(N, min(block_n, N), 1) if block_n else abn
-    bk = _fit(K, min(block_k, K), GROUP) if block_k else abk
-    grid = (M // bm, N // bn, K // bk)
-    # documented invariant: the accumulator revisit pattern needs K innermost
-    assert K_GRID_AXIS == len(grid) - 1 and grid[K_GRID_AXIS] == K // bk
-
-    return pl.pallas_call(
-        _fused_packed_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bm, bk // GROUP), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk // GROUP, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        interpret=interpret,
-    )(a_ints, a_scales, codes_km, meta_km)
+    bm, bn, bk = resolve_blocks(M, N, K, block_m, block_n, block_k)
+    a3, asc3 = group_major(a_ints, a_scales)
+    return matmul_call(_fused_packed_kernel, (a3, asc3, codes_km, meta_km),
+                       M=M, N=N, K=K, bm=bm, bn=bn, bk=bk,
+                       b_rows=lambda bk: bk // 2, interpret=interpret)
 
 
 def fused_packed_matmul_xla(a_ints, a_scales, codes_km, meta_km):

@@ -18,13 +18,11 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def quantize(x: jax.Array, *, block_m: int = 256, block_k: int = 512,
-             interpret=None):
+def quantize(x: jax.Array, *, interpret=None):
     """BF16/FP32 (M, K) -> HiF4 absorbed layout (ints int8, scales f32)."""
     if interpret is None:
         interpret = _interpret_default()
-    return hif4_quantize(x, block_m=block_m, block_k=block_k,
-                         interpret=interpret)
+    return hif4_quantize(x, interpret=interpret)
 
 
 def matmul(x: jax.Array, w: jax.Array, *, block_m: int = 256,
@@ -33,10 +31,8 @@ def matmul(x: jax.Array, w: jax.Array, *, block_m: int = 256,
     contract with the fixed-point kernel (§III.B). x (M, K) @ w (K, N)."""
     if interpret is None:
         interpret = _interpret_default()
-    ai, ascale = hif4_quantize(x, block_m=block_m, block_k=block_k,
-                               interpret=interpret)
-    wi, wscale = hif4_quantize(w.T, block_m=block_n, block_k=block_k,
-                               interpret=interpret)
+    ai, ascale = hif4_quantize(x, interpret=interpret)
+    wi, wscale = hif4_quantize(w.T, interpret=interpret)
     return bfp_matmul_quantized(
         ai, ascale, wi.T, wscale.T,
         block_m=block_m, block_n=block_n, block_k=block_k,

@@ -10,17 +10,11 @@ state (the dry-run must set XLA_FLAGS before the first jax call).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types (Auto = today's behavior)
-    from jax.sharding import AxisType
 
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: Auto is the only (implicit) behavior
-    AxisType = None
-
-    def _axis_kw(n: int) -> dict:
-        return {}
+def _axis_kw(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -58,6 +58,7 @@ from repro.configs import get_arch
 from repro.core import kvcache
 from repro.core.policy import get_policy
 from repro.core.qlinear import PackedW, QuantConfig
+from repro.jax_setup import configure_jax
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.models.common import ModelCtx
@@ -218,6 +219,7 @@ def main():
                          "re-prefilled — outputs bitwise identical to an "
                          "uninterrupted run")
     args = ap.parse_args()
+    configure_jax()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -14,6 +14,7 @@ import jax
 
 from repro.configs import get_arch
 from repro.core.qlinear import QuantConfig
+from repro.jax_setup import configure_jax
 from repro.launch.mesh import make_host_mesh
 from repro.models.common import ModelCtx
 from repro.runtime import TrainLoopConfig, train
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    configure_jax()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -100,24 +100,66 @@ def ones(shape, dtype=jnp.bfloat16):
 # ---------------------------------------------------------------------------
 
 
+def pin_bf16(x: jax.Array) -> jax.Array:
+    """A bf16 ``x`` rounded to bf16 as written; other dtypes pass.
+
+    XLA may keep a fused bf16 value in excess precision, and how much it
+    keeps depends on how each program fused. HiF4's discontinuous
+    activation quantization turns those last bits into different codes,
+    so the residual stream and the norm inputs and outputs — what the
+    quantization sites see — are pinned: ``reduce_precision`` on f32 is
+    never elided, and where XLA already rounded it is a no-op."""
+    if x.dtype != jnp.bfloat16:
+        return x
+    return jax.lax.reduce_precision(x.astype(jnp.float32), exponent_bits=8,
+                                    mantissa_bits=7).astype(x.dtype)
+
+
+def residual_add(x: jax.Array, y: jax.Array) -> jax.Array:
+    """``x + y`` on the residual stream, rounded as written."""
+    return pin_bf16(x + y)
+
+
+def row_mean(x: jax.Array) -> jax.Array:
+    """Mean over the last axis (keepdims), summed in one fixed order.
+
+    An XLA reduce adds in the order of the layout it picked for its
+    operand, and that layout follows the consumers: on a TPU v5e the same
+    norm reduced along lanes where it fed the packed kernels and along the
+    major axis where it fed the qdq fake-quant, so the two programs' norm
+    outputs differed in the last bf16 bit. Halving the axis and adding the
+    halves elementwise (zero-padded to a power of two) is one order for
+    every program, layout and backend. The mean is a multiply by the f32
+    reciprocal, which XLA already makes of a division by a constant under
+    ``jit`` but not eagerly."""
+    n = x.shape[-1]
+    width = 1 << (n - 1).bit_length()
+    if width != n:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x * (1.0 / n)
+
+
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    xf = pin_bf16(x).astype(jnp.float32)
+    var = row_mean(jnp.square(xf))
     y = xf * jax.lax.rsqrt(var + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+    return pin_bf16((y * weight.astype(jnp.float32)).astype(x.dtype))
 
 
 def layer_norm(
     x: jax.Array, weight: jax.Array, bias: Optional[jax.Array], eps: float = 1e-5
 ) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
+    xf = pin_bf16(x).astype(jnp.float32)
+    mu = row_mean(xf)
+    var = row_mean(jnp.square(xf - mu))
     y = (xf - mu) * jax.lax.rsqrt(var + eps)
     y = y * weight.astype(jnp.float32)
     if bias is not None:
         y = y + bias.astype(jnp.float32)
-    return y.astype(x.dtype)
+    return pin_bf16(y.astype(x.dtype))
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models import mamba2, moe as moe_mod, transformer as tf
-from repro.models.common import ModelCtx, cross_entropy, dense
+from repro.models.common import ModelCtx, cross_entropy, dense, residual_add
 from repro.models.params import PSpec, stack_specs, init_from_specs
 
 
@@ -260,13 +260,13 @@ def _tblock_apply(p, x, cfg, ctx, *, mode, cache=None, pos=None,
             p["attn"], h, cfg, ctx, causal=causal, use_rope=use_rope,
             return_cache=(mode == "prefill"),
         )
-    x = x + a
+    x = residual_add(x, a)
     h2 = tf.norm_apply(p["norm2"], x, cfg)
     if "moe" in p:
         f = moe_mod.moe_apply(p["moe"], h2, cfg, ctx)
     else:
         f = tf.mlp_apply(p["mlp"], h2, cfg, ctx)
-    return x + f, new_cache
+    return residual_add(x, f), new_cache
 
 
 def _scan_layers(body, x0, xs, remat: bool):
@@ -323,7 +323,7 @@ def _ssm_forward(params, x, cfg, ctx, *, mode, caches=None):
             h = ctx.shard.constrain(h, *sp)
             out, cache = mamba2.mamba_full(p_layer, h, cfg, bctx,
                                            return_cache=want_cache)
-            return h + out, cache
+            return residual_add(h, out), cache
         remat = ctx.remat and mode == "train"
         x, caches = _scan_layers(body, x, params["blocks"], remat)
         return ctx.shard.constrain(x, *sp), (caches if want_cache else None)
@@ -331,7 +331,7 @@ def _ssm_forward(params, x, cfg, ctx, *, mode, caches=None):
     def body(h, layer):
         p_layer, cache = layer
         out, new_cache = mamba2.mamba_step(p_layer, h, cache, cfg, bctx)
-        return h + out, new_cache
+        return residual_add(h, out), new_cache
     x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
     return x, new_caches
 
@@ -355,9 +355,10 @@ def _hybrid_forward(params, x, cfg, ctx, *, mode, caches=None, pos=None):
         else:
             a, new_kv = tf.attn_full(shared["attn"], hn, cfg, sctx, causal=True,
                                      return_cache=(mode == "prefill"))
-        h = h + a
+        h = residual_add(h, a)
         h2 = tf.norm_apply(shared["norm2"], h, cfg)
-        return h + tf.mlp_apply(shared["mlp"], h2, cfg, sctx), new_kv
+        f = tf.mlp_apply(shared["mlp"], h2, cfg, sctx)
+        return residual_add(h, f), new_kv
 
     if mode in ("train", "prefill"):
         want_cache = mode == "prefill"
@@ -369,7 +370,7 @@ def _hybrid_forward(params, x, cfg, ctx, *, mode, caches=None, pos=None):
             def inner(hh, p_layer):
                 out, mc = mamba2.mamba_full(p_layer, hh, cfg, bctx,
                                             return_cache=want_cache)
-                return hh + out, mc
+                return residual_add(hh, out), mc
             h, mcaches = jax.lax.scan(inner, h, p_super)
             return h, (mcaches, kv)
         remat = ctx.remat and mode == "train"
@@ -387,7 +388,7 @@ def _hybrid_forward(params, x, cfg, ctx, *, mode, caches=None, pos=None):
         def inner(hh, layer):
             p_layer, mc = layer
             out, new_mc = mamba2.mamba_step(p_layer, hh, mc, cfg, bctx)
-            return hh + out, new_mc
+            return residual_add(hh, out), new_mc
         h, new_mc = jax.lax.scan(inner, h, (p_super, mcache))
         return h, (new_mc, new_kv)
 
@@ -416,9 +417,10 @@ def _encode(params, frames, cfg, ctx):
         hn = tf.norm_apply(p_layer["norm1"], h, cfg)
         a, _ = tf.attn_full(p_layer["attn"], hn, cfg, ectx, causal=False,
                             use_rope=False)
-        h = h + a
+        h = residual_add(h, a)
         h2 = tf.norm_apply(p_layer["norm2"], h, cfg)
-        return h + tf.mlp_apply(p_layer["mlp"], h2, cfg, ectx), None
+        f = tf.mlp_apply(p_layer["mlp"], h2, cfg, ectx)
+        return residual_add(h, f), None
 
     x, _ = _scan_layers(body, x, params["enc_blocks"], ctx.remat)
     return tf.norm_apply(params["enc_norm"], x, cfg)
@@ -459,7 +461,7 @@ def _dec_block_apply(p, x, cfg, ctx, *, mode, self_cache, cross_kv, pos):
         a, new_self = tf.attn_full(p["attn"], h, cfg, ctx, causal=True,
                                    use_rope=False,
                                    return_cache=(mode == "prefill"))
-    x = x + a
+    x = residual_add(x, a)
 
     hx = tf.norm_apply(p["norm_x"], x, cfg)
     if mode == "decode":
@@ -483,10 +485,10 @@ def _dec_block_apply(p, x, cfg, ctx, *, mode, self_cache, cross_kv, pos):
         )
         a = dense(o.reshape(B, S, -1), p["xattn"]["wo"].reshape(-1, d),
                   quant=ctx.site_quant("xattn.wo"), shard=ctx.shard)
-    x = x + a
+    x = residual_add(x, a)
 
     h2 = tf.norm_apply(p["norm2"], x, cfg)
-    return x + tf.mlp_apply(p["mlp"], h2, cfg, ctx), new_self
+    return residual_add(x, tf.mlp_apply(p["mlp"], h2, cfg, ctx)), new_self
 
 
 def _audio_forward(params, dec_x, cfg, ctx, *, mode, frames=None, caches=None,

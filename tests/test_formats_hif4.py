@@ -173,12 +173,11 @@ def group_arrays(draw):
 class TestNativeBf16Path:
     @hypothesis.given(group_arrays())
     def test_bf16_native_bitwise_equals_f32_simulated(self, v):
-        """The native-bf16 Algorithm 1 must agree BITWISE with the
-        explicitly-emulated f32 path on bf16 inputs (every intermediate is
-        bf16-representable) — this is what makes the 2x QDQ-traffic
-        optimization a free lunch."""
-        g32 = hif4.quantize_groups(v)                      # f32-simulated
-        g16 = hif4.quantize_groups(v.astype(jnp.bfloat16))  # native
+        """Algorithm 1 on bf16 inputs, under ``jit``, must agree BITWISE
+        with the eager f32-emulated path: XLA may fuse bf16 arithmetic in
+        excess precision, so every bf16 rounding has to stay explicit."""
+        g32 = hif4.quantize_groups(v)                      # eager, f32
+        g16 = jax.jit(hif4.quantize_groups)(v.astype(jnp.bfloat16))
         np.testing.assert_array_equal(np.asarray(g32.e6m2), np.asarray(g16.e6m2))
         np.testing.assert_array_equal(np.asarray(g32.e1_8), np.asarray(g16.e1_8))
         np.testing.assert_array_equal(np.asarray(g32.e1_16), np.asarray(g16.e1_16))

@@ -78,8 +78,6 @@ class TestBytesAndCollectives:
     def test_collective_bytes_single_allreduce(self):
         if len(jax.devices()) < 1:
             pytest.skip("needs devices")
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        import jax.experimental.shard_map as shard_map
 
         # single device: SPMD lowering still emits the collective when we
         # force one through shard_map over a 1-device mesh -> group size 1,

@@ -29,8 +29,7 @@ class TestHiF4QuantKernel:
     @pytest.mark.parametrize("dtype", DTYPES)
     def test_matches_ref(self, m, k, dtype):
         x = _rand(jax.random.PRNGKey(m * k), m, k, dtype)
-        ints, scales = hif4_quantize(x, block_m=min(m, 32), block_k=min(k, 128),
-                                     interpret=True)
+        ints, scales = hif4_quantize(x, block_groups=128, interpret=True)
         ints_ref, scales_ref = ref.hif4_quantize_ref(x.astype(jnp.float32))
         np.testing.assert_array_equal(np.asarray(ints), np.asarray(ints_ref))
         np.testing.assert_array_equal(np.asarray(scales), np.asarray(scales_ref))

@@ -94,3 +94,51 @@ def test_fully_packed_serving_residency():
     toks = serve(CFG, serving_params, prompts, ctx,
                  ServeConfig(max_new_tokens=4, kv_format="hif4"))
     assert toks.shape == (2, 4) and bool(jnp.all(toks >= 0))
+
+
+@pytest.mark.parametrize("impl", ["qdq", "packed"])
+def test_prefill_jitted_equals_eager_bitwise(impl):
+    """HiF4 activation quantization is discontinuous, so the bf16 glue
+    feeding it (residual stream, norm inputs and outputs) must round as
+    written however XLA fuses: a jitted and an eager prefill of the same
+    model give bitwise the same logits. Tests run with XLA's default
+    (excess precision allowed), so the model's own rounding is what holds."""
+    import dataclasses
+
+    from repro.core import kvcache
+    from repro.core.policy import get_policy
+    from repro.runtime.serve_loop import (prepare_params_for_serving,
+                                          serving_ctx)
+
+    cfg = dataclasses.replace(CFG, n_layers=2)
+    plan = lm.quant_plan(cfg, get_policy("paper-iv", impl=impl,
+                                         kv=kvcache.KVCacheConfig("hif4")))
+    ctx = serving_ctx(ModelCtx(quant=plan.base, plan=plan, remat=False,
+                               attn_q_chunk=32, attn_k_chunk=32))
+    params = prepare_params_for_serving(
+        lm.init_params(cfg, jax.random.PRNGKey(0)), cfg, plan)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
+                                          cfg.vocab)}
+    jitted = jax.jit(lambda p, b: lm.prefill(p, b, cfg, ctx)[0])(params, batch)
+    with jax.disable_jit():
+        eager = lm.prefill(params, batch, cfg, ctx)[0]
+    np.testing.assert_array_equal(np.asarray(jitted, np.float32),
+                                  np.asarray(eager, np.float32))
+
+
+@pytest.mark.parametrize("width", [1024, 896, 1])
+def test_row_mean_sums_in_one_fixed_order(width):
+    """Norm statistics add in one pairwise order, not in the order of the
+    layout XLA picks for a reduce: jitted or eager, the mean is bitwise a
+    host-side halving tree over the zero-padded row."""
+    from repro.models.common import row_mean
+
+    x = np.asarray(jax.random.normal(jax.random.PRNGKey(3), (4, width)),
+                   np.float32) ** 2
+    ref = np.pad(x, ((0, 0), (0, (1 << (width - 1).bit_length()) - width)))
+    while ref.shape[-1] > 1:
+        half = ref.shape[-1] // 2
+        ref = ref[:, :half] + ref[:, half:]
+    ref = ref * np.float32(1.0 / width)
+    np.testing.assert_array_equal(np.asarray(jax.jit(row_mean)(x)), ref)
+    np.testing.assert_array_equal(np.asarray(row_mean(jnp.asarray(x))), ref)

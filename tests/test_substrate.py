@@ -20,6 +20,8 @@ from repro.models.common import ModelCtx
 from repro.optim.grad_compress import ef_compress_step, qdq_flat
 from repro.runtime import ServeConfig, TrainLoopConfig, serve, train
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 class TestData:
     def test_deterministic(self):
@@ -169,29 +171,17 @@ class TestGradCompress:
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-try:                                  # jax >= 0.5
-    from jax.sharding import AxisType
-    mesh_kw = {"axis_types": (AxisType.Auto,)}
-except ImportError:                   # older jax: Auto is implicit
-    mesh_kw = {}
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 import sys; sys.path.insert(0, "src")
 from repro.optim.grad_compress import compressed_psum
 
-mesh = jax.make_mesh((4,), ("data",), **mesh_kw)
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 1024)) * 0.1
 
 body = lambda v: compressed_psum(v[0], "data", 4)[None]
-try:
-    f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                  check_vma=False)
-except TypeError:                     # older jax spells it check_rep
-    f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                  check_rep=False)
+f = shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+              check_vma=False)
 got = np.asarray(f(x))
 want = np.asarray(jnp.mean(x, axis=0))
 for i in range(4):
@@ -199,7 +189,7 @@ for i in range(4):
     assert rel < 0.15, rel
 print("OK")
 """
-        r = subprocess.run([sys.executable, "-c", script], cwd="/root/repo",
+        r = subprocess.run([sys.executable, "-c", script], cwd=REPO_ROOT,
                            capture_output=True, text=True, timeout=300)
         assert r.returncode == 0, r.stderr[-2000:]
         assert "OK" in r.stdout

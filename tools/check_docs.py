@@ -14,9 +14,10 @@ whose prose makes cross-module claims about layouts and test anchors) for
     the path must exist;
   * quantization-policy preset references (``--policy paper-iv``,
     backticked ``uniform:<fmt>`` spellings, and backticked hyphenated
-    names on lines that mention a policy/preset): the name must resolve
-    in the ``repro.core.policy`` preset registry — docs advertising a
-    renamed or deleted preset fail CI;
+    names on lines that mention a policy/preset, scenario-matrix cell
+    names excepted): the name must resolve in the ``repro.core.policy``
+    preset registry — docs advertising a renamed or deleted preset fail
+    CI;
   * matrix perf-gate references (the ``gate:`name``` spelling): the name
     must be declared in ``benchmarks.matrix.GATE_NAMES`` — docs
     documenting a gate ``check_matrix_gates`` does not enforce fail CI;
@@ -166,9 +167,12 @@ def check_file(path: str, docstring_only: bool = False) -> list[str]:
     for ref in sorted(set(PATH_RE.findall(text))):
         if not os.path.exists(os.path.join(REPO, ref)):
             errors.append(f"{rel}: dead file reference `{ref}`")
+    from benchmarks.matrix import CELLS
     from repro.core.policy import known_policy_spec
 
-    for name in sorted(_policy_candidates(text)):
+    # a scenario-matrix cell name on a policy line names a cell, not a preset
+    cell_names = {c.name for c in CELLS}
+    for name in sorted(_policy_candidates(text) - cell_names):
         if not known_policy_spec(name):
             errors.append(
                 f"{rel}: unknown policy preset `{name}` (not in the "
