@@ -20,7 +20,6 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
@@ -396,12 +395,17 @@ def _ctx_cache_key(ctx: ModelCtx):
             ctx.attn_kv_block)
 
 
+# Each wrapper jits a function with its own name, so the program and its
+# device ops read as ``jit_serve_prefill``, ``jit_serve_decode`` etc. in a
+# profiler trace.
 def _jit_prefill(cfg: ArchConfig, sctx: ModelCtx):
     key = ("prefill", cfg, _ctx_cache_key(sctx))
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(lambda p, b: lm.prefill(p, b, cfg, sctx))
-        _JIT_CACHE[key] = fn
+        def serve_prefill(params, batch):
+            return lm.prefill(params, batch, cfg, sctx)
+
+        fn = _JIT_CACHE[key] = jax.jit(serve_prefill)
     return fn
 
 
@@ -409,8 +413,10 @@ def _jit_quantize_kv(cfg: ArchConfig):
     key = ("quantize_kv", cfg)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(lambda c: lm.quantize_kv_cache(c, cfg))
-        _JIT_CACHE[key] = fn
+        def serve_quantize_kv(cache):
+            return lm.quantize_kv_cache(cache, cfg)
+
+        fn = _JIT_CACHE[key] = jax.jit(serve_quantize_kv)
     return fn
 
 
@@ -419,12 +425,12 @@ def _jit_decode_scan(cfg: ArchConfig, sctx: ModelCtx, n_tokens: int,
     key = ("decode", cfg, _ctx_cache_key(sctx), n_tokens, eos_id)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(
-            partial(_decode_scan, n_tokens=n_tokens, cfg=cfg, sctx=sctx,
-                    eos_id=eos_id),
-            donate_argnums=(2,),            # cache updates in place
-        )
-        _JIT_CACHE[key] = fn
+        def serve_decode(params, token, cache, done):
+            return _decode_scan(params, token, cache, done, n_tokens, cfg,
+                                sctx, eos_id)
+
+        fn = _JIT_CACHE[key] = jax.jit(
+            serve_decode, donate_argnums=(2,))   # cache updates in place
     return fn
 
 
@@ -433,12 +439,12 @@ def _jit_decode_scan_guarded(cfg: ArchConfig, sctx: ModelCtx, n_tokens: int,
     key = ("decode-guarded", cfg, _ctx_cache_key(sctx), n_tokens, eos_id)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(
-            partial(_decode_scan_guarded, n_tokens=n_tokens, cfg=cfg,
-                    sctx=sctx, eos_id=eos_id),
-            donate_argnums=(2,),            # cache updates in place
-        )
-        _JIT_CACHE[key] = fn
+        def serve_decode_guarded(params, token, cache, done, bad):
+            return _decode_scan_guarded(params, token, cache, done, bad,
+                                        n_tokens, cfg, sctx, eos_id)
+
+        fn = _JIT_CACHE[key] = jax.jit(
+            serve_decode_guarded, donate_argnums=(2,))  # cache in place
     return fn
 
 
@@ -737,7 +743,18 @@ def serve_requests(
     their page bytes, everything else re-prefills from its prompt — and
     the resumed greedy outputs are verified bitwise against the journaled
     token prefixes (docs/EXECUTION.md §Crash recovery).
+
+    The paged scheduler marks its phases with profiler annotations
+    (``serve.setup``, ``serve.admit``, ``serve.prefill``, ``serve.pages``,
+    ``serve.decode``, ``serve.account``, ``serve.finish``; about a
+    microsecond of host time each while no profiler trace records)
+    and adds to ``stats`` the counters ``prefills``, ``prefill_tokens``,
+    ``decode_chunks`` and ``decode_steps`` and, per request id,
+    ``request_times``: ``admitted``, ``first_token`` and ``finished`` in
+    seconds since this call began (``time.perf_counter``), and the
+    ``tokens`` its result holds.
     """
+    t_enter = time.perf_counter()
     assert cfg.family in ("dense", "vlm", "moe"), (
         f"continuous batching supports KV-cache families, got {cfg.family!r}"
     )
@@ -748,7 +765,7 @@ def serve_requests(
                                warned=warned)
     # Resolve the jitted entry points ONCE per serve call — admission runs
     # between every decode chunk, and a dict probe per admitted request
-    # (plus the partial/jit wrapper construction on a miss) is avoidable
+    # (plus the jit wrapper construction on a miss) is avoidable
     # scheduler overhead.
     prefill = _jit_prefill(cfg, sctx)
     quantize = _jit_quantize_kv(cfg) if kv_fmt == "hif4" else None
@@ -760,7 +777,7 @@ def serve_requests(
         return _serve_requests_paged(
             cfg, params, requests, sctx, serve_cfg, ctx=ctx,
             slots=slots, prefill=prefill, quantize=quantize, stats=stats,
-            injector=injector, resume=resume)
+            injector=injector, resume=resume, t_enter=t_enter)
 
     guard = serve_cfg.guard
     budget = serve_cfg.max_new_tokens
@@ -1031,6 +1048,7 @@ def _serve_requests_paged(
     stats: Optional[dict] = None,
     injector=None,
     resume: bool = False,
+    t_enter: float,
 ) -> list:
     """Page-pool continuous batching (the :func:`serve_requests` backend
     for ``serve_cfg.kv_pages > 0``).
@@ -1082,40 +1100,42 @@ def _serve_requests_paged(
     budget = serve_cfg.max_new_tokens
     eos = serve_cfg.eos_id
     n_req = len(requests)
-    prompts = [jax.device_get(jnp.asarray(r, jnp.int32)).ravel().tolist()
-               for r in requests]
-    max_prompt = max(len(p) for p in prompts)
-    cap = serve_cfg.cache_capacity or max_prompt + budget
-    for p_toks in prompts:
-        assert len(p_toks) + budget <= cap, (
-            f"prompt {len(p_toks)} + budget {budget} exceeds capacity {cap}")
-    maxp = kvcache.pages_for_tokens(cap, P)
-    pool = kvcache.PagePool(serve_cfg.kv_pages, P)
-    assert maxp <= pool.usable_pages, (
-        f"one max-length sequence needs {maxp} pages but the pool has only "
-        f"{pool.usable_pages} usable (kv_pages={serve_cfg.kv_pages} minus "
-        f"the scratch page)")
-    B = min(slots, n_req)
+    with jax.profiler.TraceAnnotation("serve.setup"):
+        prompts = [jax.device_get(jnp.asarray(r, jnp.int32)).ravel().tolist()
+                   for r in requests]
+        max_prompt = max(len(p) for p in prompts)
+        cap = serve_cfg.cache_capacity or max_prompt + budget
+        for p_toks in prompts:
+            assert len(p_toks) + budget <= cap, (
+                f"prompt {len(p_toks)} + budget {budget} exceeds capacity "
+                f"{cap}")
+        maxp = kvcache.pages_for_tokens(cap, P)
+        pool = kvcache.PagePool(serve_cfg.kv_pages, P)
+        assert maxp <= pool.usable_pages, (
+            f"one max-length sequence needs {maxp} pages but the pool has "
+            f"only {pool.usable_pages} usable (kv_pages={serve_cfg.kv_pages} "
+            f"minus the scratch page)")
+        B = min(slots, n_req)
 
-    cache = lm.init_paged_cache(cfg, B, serve_cfg.kv_pages, P, maxp)
-    token = jnp.zeros((B,), jnp.int32)
-    done = jnp.ones((B,), bool)
+        cache = lm.init_paged_cache(cfg, B, serve_cfg.kv_pages, P, maxp)
+        token = jnp.zeros((B,), jnp.int32)
+        done = jnp.ones((B,), bool)
 
-    guard = serve_cfg.guard
-    chunk = serve_cfg.decode_chunk or max(1, budget // 4)
-    guarded = guard is not None and guard.nan_sentinel
-    if guarded:
-        gstep = _jit_decode_scan_guarded(cfg, sctx, chunk, eos)
-        zeros_bad = jnp.zeros((B,), bool)     # fresh carry, hoisted: the
-        #                                       scan never donates it
-    else:
-        step = _jit_decode_scan(cfg, sctx, chunk, eos)
-    if injector is not None:
-        injector.steal_pages(pool)
+        guard = serve_cfg.guard
+        chunk = serve_cfg.decode_chunk or max(1, budget // 4)
+        guarded = guard is not None and guard.nan_sentinel
+        if guarded:
+            gstep = _jit_decode_scan_guarded(cfg, sctx, chunk, eos)
+            zeros_bad = jnp.zeros((B,), bool)  # fresh carry, hoisted: the
+            #                                    scan never donates it
+        else:
+            step = _jit_decode_scan(cfg, sctx, chunk, eos)
+        if injector is not None:
+            injector.steal_pages(pool)
 
-    journal, plan = _open_journal(
-        serve_cfg, requests, resume=resume, kind="paged", chunk=chunk,
-        kv_pages=serve_cfg.kv_pages, page_tokens=P)
+        journal, plan = _open_journal(
+            serve_cfg, requests, resume=resume, kind="paged", chunk=chunk,
+            kv_pages=serve_cfg.kv_pages, page_tokens=P)
 
     queue = list(range(n_req))
     suspended: dict = {}               # rid -> preemption byte snapshot
@@ -1125,7 +1145,9 @@ def _serve_requests_paged(
     #                                                    resident, in order
     slot_pages: list[list] = [[] for _ in range(B)]    # pool ids, logical
     admit_clock = [0] * B
-    admit_time = [0.0] * B
+    admit_time = [0.0] * B             # time.perf_counter after admission
+    request_times: dict = {}           # rid -> seconds since t_enter
+    t_host = 0.0                       # when the last chunk's tokens landed
     results: list = [None] * n_req
     reports = {rid: guard_mod.new_report() for rid in range(n_req)}
     if plan is not None:
@@ -1151,6 +1173,7 @@ def _serve_requests_paged(
     peak_live = 0
     snapshot_drops = 0
     chunk_idx = 0
+    prefills = prefill_tokens = 0
     # Page-checksum audit state: ``recorded`` maps pool page id -> the
     # byte-sum observed after the last chunk; ``dirty`` collects pages the
     # scheduler itself wrote since then (admission scatters, COW copies,
@@ -1235,7 +1258,8 @@ def _serve_requests_paged(
                 return None
 
     def try_admit(b, rid):
-        nonlocal token, done, clock, snapshot_drops
+        nonlocal token, done, clock, snapshot_drops, prefills, prefill_tokens
+        t_try = time.perf_counter()
         snap = suspended.get(rid)
         if snap is not None and not guard_mod.verify_snapshot(snap):
             # a truncated/flipped snapshot must never reach the pool:
@@ -1270,56 +1294,62 @@ def _serve_requests_paged(
         else:
             toks = prompts[rid]
             n_tok = len(toks)
-            logits, slot_cache = prefill(
-                params, {"tokens": jnp.asarray(toks, jnp.int32).reshape(1, -1)})
-            slot_cache = quantize(slot_cache)
-            kp = kvcache.split_pages(slot_cache["kv"]["k"], P)
-            vp = kvcache.split_pages(slot_cache["kv"]["v"], P)
-            n_pg = kvcache.pages_for_tokens(n_tok, P)
-            share = [None] * n_pg
-            if serve_cfg.prefix_sharing:
+            prefills += 1
+            prefill_tokens += n_tok
+            with jax.profiler.TraceAnnotation("serve.prefill", rid=rid,
+                                              tokens=n_tok):
+                logits, slot_cache = prefill(params, {
+                    "tokens": jnp.asarray(toks, jnp.int32).reshape(1, -1)})
+                slot_cache = quantize(slot_cache)
+                kp = kvcache.split_pages(slot_cache["kv"]["k"], P)
+                vp = kvcache.split_pages(slot_cache["kv"]["v"], P)
+                n_pg = kvcache.pages_for_tokens(n_tok, P)
+                share = [None] * n_pg
+                if serve_cfg.prefix_sharing:
+                    for j in range(n_pg):
+                        seg = toks[j * P:(j + 1) * P]
+                        if len(seg) == P:
+                            cand = pool.lookup_full(
+                                tuple(toks[: (j + 1) * P]))
+                        else:
+                            cand = pool.lookup_partial(
+                                tuple(toks[: j * P]), seg)
+                        if cand is None:
+                            continue
+                        page_k = {key: a[:, j] for key, a in kp.items()}
+                        page_v = {key: a[:, j] for key, a in vp.items()}
+                        if bool(jax.device_get(_page_equal_jit(
+                                cache["kv"], cand, page_k, page_v, len(seg)))):
+                            share[j] = cand
+                n_new = sum(1 for s in share if s is None)
+                n_revive = sum(1 for s in share
+                               if s is not None and s in pool.cached)
+                if pool.available() < n_new + n_revive:
+                    return False
+                # retain every shared page BEFORE allocating: alloc may evict
+                # from the LRU cache, and a not-yet-retained candidate must
+                # not be its victim
+                for s in share:
+                    if s is not None:
+                        pool.retain(s)
+                        pool.shared_hits += 1
+                pids = []
+                own_src, own_dst = [], []
                 for j in range(n_pg):
-                    seg = toks[j * P:(j + 1) * P]
-                    if len(seg) == P:
-                        cand = pool.lookup_full(tuple(toks[: (j + 1) * P]))
+                    if share[j] is not None:
+                        pids.append(share[j])
                     else:
-                        cand = pool.lookup_partial(tuple(toks[: j * P]), seg)
-                    if cand is None:
-                        continue
-                    page_k = {key: a[:, j] for key, a in kp.items()}
-                    page_v = {key: a[:, j] for key, a in vp.items()}
-                    if bool(jax.device_get(_page_equal_jit(
-                            cache["kv"], cand, page_k, page_v, len(seg)))):
-                        share[j] = cand
-            n_new = sum(1 for s in share if s is None)
-            n_revive = sum(1 for s in share
-                           if s is not None and s in pool.cached)
-            if pool.available() < n_new + n_revive:
-                return False
-            # retain every shared page BEFORE allocating: alloc may evict
-            # from the LRU cache, and a not-yet-retained candidate must
-            # not be its victim
-            for s in share:
-                if s is not None:
-                    pool.retain(s)
-                    pool.shared_hits += 1
-            pids = []
-            own_src, own_dst = [], []
-            for j in range(n_pg):
-                if share[j] is not None:
-                    pids.append(share[j])
-                else:
-                    pid = pool.alloc(owner=rid)
-                    own_src.append(j)
-                    own_dst.append(pid)
-                    pids.append(pid)
-            if own_dst:
-                cache["kv"] = _pool_scatter_jit(
-                    cache["kv"], kp, vp,
-                    jnp.asarray(own_src, jnp.int32),
-                    jnp.asarray(own_dst, jnp.int32))
-                dirty.update(own_dst)
-            first = int(jax.device_get(jnp.argmax(logits, axis=-1))[0])
+                        pid = pool.alloc(owner=rid)
+                        own_src.append(j)
+                        own_dst.append(pid)
+                        pids.append(pid)
+                if own_dst:
+                    cache["kv"] = _pool_scatter_jit(
+                        cache["kv"], kp, vp,
+                        jnp.asarray(own_src, jnp.int32),
+                        jnp.asarray(own_dst, jnp.int32))
+                    dirty.update(own_dst)
+                first = int(jax.device_get(jnp.argmax(logits, axis=-1))[0])
             token = token.at[b].set(first)
             cache["pos"] = cache["pos"].at[b].set(n_tok)
             done = done.at[b].set(eos is not None and first == eos)
@@ -1330,7 +1360,9 @@ def _serve_requests_paged(
         set_table_row(b, pids)
         clock += 1
         admit_clock[b] = clock
-        admit_time[b] = time.monotonic()
+        admit_time[b] = time.perf_counter()
+        times = request_times.setdefault(rid, {"admitted": t_try - t_enter})
+        times.setdefault("first_token", admit_time[b] - t_enter)
         refresh_metadata(b)
         if journal is not None:
             # an admitted record RESETS the rid's journaled emission to
@@ -1381,9 +1413,14 @@ def _serve_requests_paged(
         slot_written[b] = []
         set_table_row(b, [])
 
+    def finished_at(rid, tokens):
+        request_times[rid].update(finished=t_host - t_enter, tokens=tokens)
+
     def retire(b):
         rid = slot_req[b]
         results[rid] = _finalize_result(slot_toks[b], budget, eos)
+        toks = slot_toks[b][:budget]
+        finished_at(rid, toks.index(eos) + 1 if eos in toks else len(toks))
         release_slot(b)
         jlog_done(rid)
 
@@ -1444,7 +1481,11 @@ def _serve_requests_paged(
             if free_b is None:
                 break
             head = queue[0]
-            if not try_admit(free_b, head):
+            with jax.profiler.TraceAnnotation(
+                    "serve.admit", rid=head,
+                    src="snapshot" if head in suspended else "prefill"):
+                admitted = try_admit(free_b, head)
+            if not admitted:
                 break
             queue.pop(0)
             if injector is not None:
@@ -1471,9 +1512,11 @@ def _serve_requests_paged(
             reject(rid, "pool_exhausted: " + msg + " after "
                    f"{attempts - 1} retries")
             continue
-        for b in range(B):
-            if slot_req[b] is not None:
-                provision(b)
+        with jax.profiler.TraceAnnotation(
+                "serve.pages", slots=sum(r is not None for r in slot_req)):
+            for b in range(B):
+                if slot_req[b] is not None:    # provision may preempt
+                    provision(b)
         # counted AFTER provisioning: sequences actually decoding this
         # chunk, not admissions that provisioning preempted right back out
         max_concurrent = max(max_concurrent,
@@ -1482,158 +1525,169 @@ def _serve_requests_paged(
         if injector is not None:
             cache["kv"] = injector.poison_pool(cache["kv"], pool, slot_req,
                                                slot_pages, chunk_idx)
-        active = jnp.asarray([r is not None for r in slot_req])
-        if guarded:
-            toks, token, cache, done, flags = gstep(
-                params, token, cache, done | ~active, zeros_bad)
-            host_toks, flagsv = jax.device_get((toks, flags))
-            badv = flagsv[:B].astype(bool)
-            pagemeta = flagsv[B:]              # per-pool-page 0xFF counts
-        else:
-            toks, token, cache, done = step(params, token, cache,
-                                            done | ~active)
-            badv = pagemeta = None
-            host_toks = jax.device_get(toks)
+        with jax.profiler.TraceAnnotation("serve.decode", chunk=chunk_idx):
+            active = jnp.asarray([r is not None for r in slot_req])
+            if guarded:
+                toks, token, cache, done, flags = gstep(
+                    params, token, cache, done | ~active, zeros_bad)
+                host_toks, flagsv = jax.device_get((toks, flags))
+                badv = flagsv[:B].astype(bool)
+                pagemeta = flagsv[B:]          # per-pool-page 0xFF counts
+            else:
+                toks, token, cache, done = step(params, token, cache,
+                                                done | ~active)
+                badv = pagemeta = None
+                host_toks = jax.device_get(toks)
+        t_host = time.perf_counter()
         chunk_idx += 1
-        # 1) account this chunk's KV writes (and mark their pages dirty)
-        chunk_emitted = {}
-        for b in range(B):
-            if slot_req[b] is None:
-                continue
-            new = [int(t) for t in host_toks[b]]
-            chunk_emitted[slot_req[b]] = new
-            # this chunk wrote KV for the previously pending token plus
-            # every emission except the newest (still pending)
-            pending = slot_toks[b][-1]
-            n0 = len(slot_written[b])
-            slot_written[b].extend([pending] + new[:-1])
-            slot_toks[b].extend(new)
-            n1 = len(slot_written[b])
-            for j in range(n0 // P, (n1 - 1) // P + 1):
-                # over-emission past the table clamps into the last entry
-                dirty.add(slot_pages[b][min(j, len(slot_pages[b]) - 1)])
-        if journal is not None:
-            journal.append("chunk", idx=chunk_idx - 1, emitted=chunk_emitted)
-        # 2) audit live pages BEFORE retiring anything, so a final-chunk
-        #    fault cannot slip out with the request. The per-page 0xFF
-        #    counts come fused out of the guarded scan; only the checksum
-        #    audit needs a second (sums-only) reduction.
-        faulty = {}
-        if (guard is not None and guard.meta_audit and pagemeta is None):
-            pagemeta = jax.device_get(
-                guard_mod.slot_meta_nan_jit(cache["kv"]))
-        sums = None
-        if guard is not None and guard.page_checksums:
-            sums = jax.device_get(
-                guard_mod.pool_page_sums_jit(cache["kv"]))
-        if guard is not None:
+        with jax.profiler.TraceAnnotation("serve.account"):
+            # 1) account this chunk's KV writes (and mark their pages dirty)
+            chunk_emitted = {}
             for b in range(B):
                 if slot_req[b] is None:
                     continue
-                for pid in slot_pages[b]:
-                    if (guard.meta_audit and pagemeta is not None
-                            and int(pagemeta[pid])):
-                        faulty[b] = (f"meta_nan: page {pid} carries "
-                                     f"{int(pagemeta[pid])} E6M2 "
-                                     "NaN sentinel(s)")
-                        break
-                    if (sums is not None and pid in recorded
-                            and pid not in dirty
-                            and int(sums[pid]) != recorded[pid]):
-                        faulty[b] = (f"page_checksum: settled page {pid} "
-                                     "changed outside the scheduler")
-                        break
-        for b in range(B):
-            if slot_req[b] is not None and b not in faulty and badv is not None \
-                    and bool(badv[b]):
-                faulty[b] = "nan_logits: non-finite logits in the decode scan"
-        for b, reason in faulty.items():
-            quarantine(b, reason)
-        # 3) re-record checksums for the pages still live, then settle
-        if sums is not None:
-            for b in range(B):
-                if slot_req[b] is None:
-                    continue
-                for pid in slot_pages[b]:
-                    recorded[pid] = int(sums[pid])
-        dirty.clear()
-        # 4) sharing metadata, deadlines, retirement
-        for b in range(B):
-            if slot_req[b] is None:
-                continue
-            refresh_metadata(b)
-            if (guard is not None and guard.deadline_s is not None
-                    and time.monotonic() - admit_time[b] > guard.deadline_s):
-                rid = slot_req[b]
-                results[rid] = _finalize_partial(slot_toks[b], budget, eos)
-                reports[rid].update(
-                    status="timeout",
-                    detail=f"deadline: exceeded {guard.deadline_s}s")
-                release_slot(b)
-                done = done.at[b].set(True)
-                jlog_done(rid)
-                continue
-            finished = len(slot_toks[b]) >= budget or (
-                eos is not None and eos in slot_toks[b])
-            if finished:
-                retire(b)
-        # 5) durability: periodic pool checkpoint, then ONE fsync for the
-        #    whole chunk's records
-        if journal is not None:
-            if (serve_cfg.checkpoint_every > 0
-                    and chunk_idx % serve_cfg.checkpoint_every == 0
-                    and any(r is not None for r in slot_req)):
-                from repro.runtime import journal as journal_mod
-                residents = {}
+                new = [int(t) for t in host_toks[b]]
+                chunk_emitted[slot_req[b]] = new
+                # this chunk wrote KV for the previously pending token plus
+                # every emission except the newest (still pending)
+                pending = slot_toks[b][-1]
+                n0 = len(slot_written[b])
+                slot_written[b].extend([pending] + new[:-1])
+                slot_toks[b].extend(new)
+                n1 = len(slot_written[b])
+                for j in range(n0 // P, (n1 - 1) // P + 1):
+                    # over-emission past the table clamps into the last entry
+                    dirty.add(slot_pages[b][min(j, len(slot_pages[b]) - 1)])
+            if journal is not None:
+                journal.append("chunk", idx=chunk_idx - 1,
+                               emitted=chunk_emitted)
+            # 2) audit live pages BEFORE retiring anything, so a final-chunk
+            #    fault cannot slip out with the request. The per-page 0xFF
+            #    counts come fused out of the guarded scan; only the checksum
+            #    audit needs a second (sums-only) reduction.
+            faulty = {}
+            if (guard is not None and guard.meta_audit and pagemeta is None):
+                pagemeta = jax.device_get(
+                    guard_mod.slot_meta_nan_jit(cache["kv"]))
+            sums = None
+            if guard is not None and guard.page_checksums:
+                sums = jax.device_get(
+                    guard_mod.pool_page_sums_jit(cache["kv"]))
+            if guard is not None:
                 for b in range(B):
-                    rid = slot_req[b]
-                    if rid is None:
+                    if slot_req[b] is None:
                         continue
-                    ids = jnp.asarray(slot_pages[b], jnp.int32)
-                    residents[rid] = {
-                        "pages": jax.device_get(
-                            _pool_gather_jit(cache["kv"], ids)),
-                        "token": int(jax.device_get(token[b])),
-                        "toks": [int(t) for t in slot_toks[b]],
-                    }
-                fname, digest = journal_mod.save_pool_checkpoint(
-                    serve_cfg.journal_dir, chunk_idx, residents)
-                if injector is not None:
-                    # the .npz is on disk but its journal record is not:
-                    # crash_during_checkpoint leaves an orphan recovery
-                    # must ignore
-                    injector.crash_point("during_checkpoint",
-                                         chunk_idx=chunk_idx - 1,
-                                         journal=journal)
-                journal.append(
-                    "checkpoint", chunk=chunk_idx, file=fname, sha256=digest,
-                    residents={rid: {"token": ent["token"],
-                                     "toks": ent["toks"]}
-                               for rid, ent in residents.items()})
-            journal.commit()
+                    for pid in slot_pages[b]:
+                        if (guard.meta_audit and pagemeta is not None
+                                and int(pagemeta[pid])):
+                            faulty[b] = (f"meta_nan: page {pid} carries "
+                                         f"{int(pagemeta[pid])} E6M2 "
+                                         "NaN sentinel(s)")
+                            break
+                        if (sums is not None and pid in recorded
+                                and pid not in dirty
+                                and int(sums[pid]) != recorded[pid]):
+                            faulty[b] = (f"page_checksum: settled page {pid} "
+                                         "changed outside the scheduler")
+                            break
+            for b in range(B):
+                if (slot_req[b] is not None and b not in faulty
+                        and badv is not None and bool(badv[b])):
+                    faulty[b] = ("nan_logits: non-finite logits in the "
+                                 "decode scan")
+            for b, reason in faulty.items():
+                quarantine(b, reason)
+            # 3) re-record checksums for the pages still live, then settle
+            if sums is not None:
+                for b in range(B):
+                    if slot_req[b] is None:
+                        continue
+                    for pid in slot_pages[b]:
+                        recorded[pid] = int(sums[pid])
+            dirty.clear()
+            # 4) sharing metadata, deadlines, retirement
+            for b in range(B):
+                if slot_req[b] is None:
+                    continue
+                refresh_metadata(b)
+                if (guard is not None and guard.deadline_s is not None
+                        and time.perf_counter() - admit_time[b]
+                        > guard.deadline_s):
+                    rid = slot_req[b]
+                    results[rid] = _finalize_partial(slot_toks[b], budget,
+                                                     eos)
+                    finished_at(rid, len(slot_toks[b][:budget]))
+                    reports[rid].update(
+                        status="timeout",
+                        detail=f"deadline: exceeded {guard.deadline_s}s")
+                    release_slot(b)
+                    done = done.at[b].set(True)
+                    jlog_done(rid)
+                    continue
+                finished = len(slot_toks[b]) >= budget or (
+                    eos is not None and eos in slot_toks[b])
+                if finished:
+                    retire(b)
+            # 5) durability: periodic pool checkpoint, then ONE fsync for the
+            #    whole chunk's records
+            if journal is not None:
+                if (serve_cfg.checkpoint_every > 0
+                        and chunk_idx % serve_cfg.checkpoint_every == 0
+                        and any(r is not None for r in slot_req)):
+                    from repro.runtime import journal as journal_mod
+                    residents = {}
+                    for b in range(B):
+                        rid = slot_req[b]
+                        if rid is None:
+                            continue
+                        ids = jnp.asarray(slot_pages[b], jnp.int32)
+                        residents[rid] = {
+                            "pages": jax.device_get(
+                                _pool_gather_jit(cache["kv"], ids)),
+                            "token": int(jax.device_get(token[b])),
+                            "toks": [int(t) for t in slot_toks[b]],
+                        }
+                    fname, digest = journal_mod.save_pool_checkpoint(
+                        serve_cfg.journal_dir, chunk_idx, residents)
+                    if injector is not None:
+                        # the .npz is on disk but its journal record is not:
+                        # crash_during_checkpoint leaves an orphan recovery
+                        # must ignore
+                        injector.crash_point("during_checkpoint",
+                                             chunk_idx=chunk_idx - 1,
+                                             journal=journal)
+                    journal.append(
+                        "checkpoint", chunk=chunk_idx, file=fname,
+                        sha256=digest,
+                        residents={rid: {"token": ent["token"],
+                                         "toks": ent["toks"]}
+                                   for rid, ent in residents.items()})
+                journal.commit()
         if injector is not None:
             injector.crash_point("mid_decode", chunk_idx=chunk_idx - 1,
                                  journal=journal)
-    if journal is not None:
-        journal.close()
-    holders = {f"slot{b}": slot_pages[b] for b in range(B) if slot_pages[b]}
-    if injector is not None and injector.held_pages:
-        holders["__fault_injector__"] = list(injector.held_pages)
-    audit = pool.audit(holders=holders)
-    if plan is not None:
-        verified = _verify_recovery(plan, results, reports)
+    with jax.profiler.TraceAnnotation("serve.finish"):
+        if journal is not None:
+            journal.close()
+        holders = {f"slot{b}": slot_pages[b] for b in range(B)
+                   if slot_pages[b]}
+        if injector is not None and injector.held_pages:
+            holders["__fault_injector__"] = list(injector.held_pages)
+        audit = pool.audit(holders=holders)
+        if plan is not None:
+            verified = _verify_recovery(plan, results, reports)
+            if stats is not None:
+                stats["recovery"] = dict(plan.report(), verified=verified)
         if stats is not None:
-            stats["recovery"] = dict(plan.report(), verified=verified)
-    if stats is not None:
-        stats.update(
-            scheduler="paged", max_concurrent=max_concurrent,
-            preemptions=preempt_count, evictions=pool.evictions,
-            shared_page_hits=pool.shared_hits,
-            pages_total=serve_cfg.kv_pages, page_tokens=P,
-            peak_live_pages=peak_live,
-            pool_bytes=serve_cfg.kv_pages * kvcache.page_nbytes(
-                cfg.attn.n_kv_heads, cfg.attn.d_head, P, cfg.n_layers),
-            snapshot_drops=snapshot_drops, pool_audit=audit,
-            reports=reports,
-            **_report_counts(reports))
+            stats.update(
+                scheduler="paged", max_concurrent=max_concurrent,
+                preemptions=preempt_count, evictions=pool.evictions,
+                shared_page_hits=pool.shared_hits,
+                peak_live_pages=peak_live,
+                snapshot_drops=snapshot_drops, pool_audit=audit,
+                reports=reports,
+                prefills=prefills, prefill_tokens=prefill_tokens,
+                decode_chunks=chunk_idx, decode_steps=chunk_idx * chunk,
+                request_times=request_times,
+                **_report_counts(reports))
     return results
