@@ -315,8 +315,8 @@ def attention_decode(
 
     With ``pages`` set, ``k_cache``/``v_cache`` are page-POOL leaves
     ((n_pages, F, P), ``repro.core.kvcache.init_page_pool``) and the same
-    dispatch picks the paged kernel / paged XLA twin — the KV-tile grid
-    axis walks the page table instead of a contiguous token axis.
+    dispatch picks the paged kernel / paged XLA twin — they walk each
+    slot's live page-table entries instead of a contiguous token axis.
     ``block_kv`` overrides the contiguous tile size (the paged tile IS
     the page size); serving threads it from ``ModelCtx.attn_kv_block`` so
     a solo reference run can align its tile partition with a paged run

@@ -165,20 +165,35 @@ def expand_codes_km(codes_km: jnp.ndarray) -> jnp.ndarray:
     c = codes_km.astype(jnp.int32)
     half, bn = codes_km.shape
     c4 = jnp.stack([c & 0xF, c >> 4], axis=1).reshape(half * 2, bn)
+    return _signed_quarters(c4)
+
+
+def _signed_quarters(c4: jnp.ndarray) -> jnp.ndarray:
+    """4-bit S1P2 codes (int32) -> signed quarters."""
     mag = c4 & 0x7
     return jnp.where((c4 >> 3) & 1 == 1, -mag, mag)
+
+
+def _micro_shift(w8, w16, r):
+    """E1_8 + E1_16 micro-exponent sum of in-group rows ``r`` (bg, R, bn)."""
+    return (((w8[:, None, :] >> (r // 8)) & 1)
+            + ((w16[:, None, :] >> (r // 4)) & 1))
 
 
 def _meta_shift_scale(meta_km: jnp.ndarray):
     """(bg, bn) uint32 metadata -> (shift (bg, 64, bn) int32, scale
     (bg, bn) f32): the grouped form :func:`expand_meta_km` flattens."""
     bg, bn = meta_km.shape
+    w8, w16, scale = _meta_fields(meta_km)
+    r = jax.lax.broadcasted_iota(jnp.int32, (bg, GROUP_SIZE, bn), 1)
+    return _micro_shift(w8, w16, r), scale
+
+
+def _meta_fields(meta_km: jnp.ndarray):
+    """(bg, bn) uint32 metadata -> (E1_8 bits, E1_16 bits, scale f32)."""
     m = jax.lax.bitcast_convert_type(meta_km, jnp.int32)
     w8 = jax.lax.shift_right_logical(m, 16) & 0xFF   # E1_8 bits
     w16 = m & 0xFFFF                                  # E1_16 bits
-    r = jax.lax.broadcasted_iota(jnp.int32, (bg, GROUP_SIZE, bn), 1)
-    shift = (((w8[:, None, :] >> (r // 8)) & 1)
-             + ((w16[:, None, :] >> (r // 4)) & 1))
     code = jax.lax.shift_right_logical(m, 24)
     # 2^eb built by exponent-field bitcast: jnp.exp2 is a polynomial
     # approximation that is NOT exact across the E6M2 range (observed
@@ -191,7 +206,7 @@ def _meta_shift_scale(meta_km: jnp.ndarray):
     # E6M2 0xFF is NaN (never produced by Algorithm 1, but corrupted bits
     # must decode identically on every path — decode_e6m2 parity)
     scale = jnp.where(code == 0xFF, jnp.nan, scale)
-    return shift, scale
+    return w8, w16, scale
 
 
 def expand_meta_km(meta_km: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -231,6 +246,27 @@ def dequantize_km(codes_km: jnp.ndarray, meta_km: jnp.ndarray,
     quarters = expand_codes_km(codes_km).reshape(bg, GROUP_SIZE, bn)
     vals = scale[:, None, :] * (quarters << shift).astype(jnp.float32)
     return vals.reshape(bg * GROUP_SIZE, bn).astype(dtype)
+
+
+def dequantize_km_split(codes_km: jnp.ndarray, meta_km: jnp.ndarray):
+    """K-major packed buffers -> (even rows, odd rows), each (K/2, N) f32.
+
+    The values :func:`dequantize_km` gives (before its cast), with the
+    even and odd contraction rows — a code byte's low and high nibble —
+    left apart: interleaving them as arrays is a costly sublane shuffle
+    in a TPU kernel, where two stride-2 row stores into VMEM do it."""
+    w8, w16, scale = _meta_fields(meta_km)
+    bg, bn = meta_km.shape
+    half = codes_km.shape[0]
+    c = codes_km.astype(jnp.int32).reshape(bg, GROUP_SIZE // 2, bn)
+    r = 2 * jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+    rows = []
+    for parity, nibble in enumerate((c & 0xF, c >> 4)):
+        quarters = _signed_quarters(nibble)
+        shift = _micro_shift(w8, w16, r + parity)
+        vals = scale[:, None, :] * (quarters << shift).astype(jnp.float32)
+        rows.append(vals.reshape(half, bn))
+    return tuple(rows)
 
 
 # ---------------------------------------------------------------------------

@@ -44,18 +44,29 @@ identically on every path.
 
 PAGED variant: when the KV cache lives in the fixed-size page pool of
 ``repro.core.kvcache`` (leaves (n_pages, F, P), per-slot page table — see
-docs/FORMATS.md "Paged KV-cache pool"), the same recurrence runs with the
-KV-tile grid axis walking the page table instead of a contiguous token
-axis. :func:`fused_paged_decode_attention` prefetches the (B, max_pages)
-table as a scalar-prefetch operand and gathers each tile's pool page in
-the BlockSpec index map; :func:`fused_paged_decode_attention_xla` is its
-bitwise twin (a scan whose tile loader is a page gather instead of a
-token slice). Because a fully masked tile is an exact no-op of the
-recurrence (``exp(NEG_INF - m)`` underflows to f32 zero and the
-correction factor is exactly 1.0), paged attention over pages of P
-tokens is BITWISE equal to the contiguous kernel/twin run with
-``block_kv=P`` on a capacity padded to a page multiple — the parity
-``tests/test_paged_kv.py`` pins.
+docs/FORMATS.md "Paged KV-cache pool"), the same recurrence walks the
+page table instead of a contiguous token axis, and only its live part.
+:func:`fused_paged_decode_attention` prefetches the (B, max_pages) table
+and the lengths as scalar-prefetch operands. Its grid is (slot, block of
+table entries): each step covers a 128-lane tile's worth of pages (two of
+64 tokens) with all KV heads, each page its own pipelined operand whose
+index map reads the table, so each page is one DMA, fetched while the
+previous block is folded. (Mosaic refuses a hand-written DMA of one page:
+the page's 64-token lane axis is half a lane tile.) The block's pages are
+dequantized and scored side by side at full lane width, then folded one
+page at a time. Entries at or past ``ceil(length / P)`` get no DMA — the
+operand keeps the page it holds — and their pages no compute.
+:func:`fused_paged_decode_attention_xla` is its bitwise twin (a scan
+whose tile loader is a page gather instead of a token slice, which drops
+the p·V of a slot's tiles past its live pages). A fully masked tile is a
+no-op of the recurrence up to one rounding (``exp(NEG_INF - m)``
+underflows to f32 zero and the correction factor is exactly 1.0, but the
+rescale l·corr/l_new goes through the chip's f32 division, which can
+land one ulp below 1), so the kernel still applies that rescale once per
+entry past the length, and paged attention over pages of P tokens stays
+BITWISE equal to the contiguous kernel/twin run with ``block_kv=P`` on a
+capacity padded to a page multiple — the parity ``tests/test_paged_kv.py``
+pins.
 """
 from __future__ import annotations
 
@@ -163,10 +174,22 @@ def _online_softmax_tile(q, kc, km, vc, vm, length, first_pos, m_ref, l_ref,
     # expand the 4.5-bit tile to bf16 K/V columns IN VMEM (K-major helpers)
     kT = hif4.dequantize_km(kc, km).reshape(hb, d_head, ck)
     vT = hif4.dequantize_km(vc, vm).reshape(hb, d_head, ck)
-    s = jax.lax.dot_general(
+    _fold_tile(_scores(q, kT, d_head), vT, length, first_pos, m_ref, l_ref,
+               acc_ref)
+
+
+def _scores(q, kT, d_head: int):
+    """q (hb, rep, D) against K columns (hb, D, ck) -> (hb, rep, ck) f32."""
+    return jax.lax.dot_general(
         q, kT, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-    ) / (d_head ** 0.5)                                  # (hb, rep, ck)
+    ) / (d_head ** 0.5)
+
+
+def _fold_tile(s, vT, length, first_pos, m_ref, l_ref, acc_ref):
+    """Fold one KV tile — its scores s (hb, rep, ck) and values vT
+    (hb, D, ck) of tokens first_pos .. first_pos+ck-1 — into the state."""
+    ck = s.shape[-1]
     kp = first_pos + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ck), 2)
     s = jnp.where(kp < length, s, NEG_INF)
 
@@ -182,6 +205,22 @@ def _online_softmax_tile(q, kc, km, vc, vm, length, first_pos, m_ref, l_ref,
         preferred_element_type=jnp.float32,
     )                                                    # (hb, rep, D)
     acc_ref[...] = acc_ref[...] * (l_prev * corr / l_new) + pv
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _fold_masked_tile(m_ref, l_ref, acc_ref):
+    """What :func:`_fold_tile` does with a tile whose every token is past
+    ``length`` (scores all NEG_INF, so p = 0 and pv = 0), without its
+    scores or values: m and l stay, and acc is scaled by l*corr/l_new —
+    exactly 1 in IEEE arithmetic, but the chip's f32 division can round
+    it one ulp below, so the tile still moves acc's last bits."""
+    m_prev = m_ref[..., :1]
+    l_prev = l_ref[..., :1]
+    m_new = jnp.maximum(m_prev, NEG_INF)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr
+    acc_ref[...] = acc_ref[...] * (l_prev * corr / l_new)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -345,17 +384,83 @@ def fused_decode_attention_xla(
 # ---------------------------------------------------------------------------
 
 
-def _fused_paged_kernel(pt_ref, len_ref, q_ref, kc_ref, km_ref, vc_ref,
-                        vm_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                        d_head: int, n_tiles: int):
-    # Scalar-prefetch kernels receive the prefetched operands first; the
-    # page-table gather happened in the BlockSpec index maps, so the body
-    # is EXACTLY one contiguous-kernel tile (same ops, same order ->
-    # bitwise).
-    del pt_ref
-    _fused_decode_kernel(len_ref, q_ref, kc_ref, km_ref, vc_ref, vm_ref,
-                         o_ref, m_ref, l_ref, acc_ref, d_head=d_head,
-                         n_steps=n_tiles, block_kv=kc_ref.shape[-1])
+def _live_pages(len_ref, b, P: int, n_tiles: int):
+    """Table entries of slot ``b`` that hold a token: ``ceil(length/P)``,
+    at most the table (a finished slot's length runs past it)."""
+    return jnp.minimum(pl.cdiv(len_ref[b], P), n_tiles)
+
+
+def _fused_paged_kernel(pt_ref, len_ref, q_ref, *refs, d_head: int,
+                        n_tiles: int, pages_per_block: int):
+    """Grid step (slot b, block j): fold the live pages of block j.
+
+    ``refs`` holds, per page i of the block, that page's K codes, K meta,
+    V codes and V meta blocks, then the output, the softmax scratch and
+    the f32 K/V scratch. The block's pages are dequantized and scored side
+    by side, a lane tile's worth at once, then folded one by one, in table
+    order. The table's entries past the live pages are fully masked
+    tiles: they are read and scored not at all, and folded by
+    :func:`_fold_masked_tile`, so the state ends as a walk over the whole
+    table leaves it, bit for bit.
+    """
+    pb = pages_per_block
+    pages, (o_ref, m_ref, l_ref, acc_ref, kv_ref) = refs[:4 * pb], refs[4 * pb:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    P = pages[0].shape[-1]
+    length = len_ref[b]
+    n_live = _live_pages(len_ref, b, P, n_tiles)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * pb < n_live)
+    def _fold_block():
+        q = q_ref[0]                                     # (Hkv, rep, D) bf16
+        leaves = [jnp.concatenate([pages[4 * i + f][0] for i in range(pb)],
+                                  axis=-1) for f in range(4)]
+        kT, vT = _dequantize_block(*leaves, kv_ref, q.shape[0], d_head)
+        s = _scores(q, kT, d_head)                       # (Hkv, rep, pb*P)
+        for i in range(pb):
+            cols = slice(i * P, (i + 1) * P)
+            pl.when(j * pb + i < n_live)(functools.partial(
+                _fold_tile, s[..., cols], vT[..., cols], length,
+                (j * pb + i) * P, m_ref, l_ref, acc_ref))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _fin():
+        @pl.loop(0, jnp.where(n_live > 0, n_tiles - n_live, 0))
+        def _masked(_):
+            _fold_masked_tile(m_ref, l_ref, acc_ref)
+
+        o_ref[0] = acc_ref[...]
+
+
+def _dequantize_block(kc, km, vc, vm, kv_ref, hb: int, d_head: int):
+    """Expand a 4.5-bit K/V block to bf16 (hb, D, ck) columns through
+    VMEM: each of K and V lands in kv_ref[0] / kv_ref[1] (F, ck) f32 as
+    its even and odd feature rows, two stride-2 row stores, and is read
+    back whole — the values ``hif4.dequantize_km`` gives, without the
+    sublane shuffle that interleaving them as arrays costs."""
+    half = kc.shape[0]
+    out = []
+    for i, (codes, meta) in enumerate(((kc, km), (vc, vm))):
+        even, odd = hif4.dequantize_km_split(codes, meta)
+        kv_ref[i, pl.ds(0, half, stride=2), :] = even
+        kv_ref[i, pl.ds(1, half, stride=2), :] = odd
+        out.append(kv_ref[i].astype(jnp.bfloat16).reshape(hb, d_head, -1))
+    return out
+
+
+def _pages_per_block(page_tokens: int, n_tiles: int) -> int:
+    """Pages one grid step of the paged walk holds: a 128-lane tile's worth
+    (2 pages of 64 tokens), so the block is dequantized and scored at
+    full lane width; one page where a page fills the lanes or does not
+    divide them; never more than the table holds."""
+    pb = _LANE // page_tokens if _LANE % page_tokens == 0 else 1
+    return max(1, min(pb, n_tiles))
 
 
 @functools.partial(
@@ -374,44 +479,51 @@ def fused_paged_decode_attention(
 ) -> jax.Array:
     """Flash decode-attention off the PAGED 4.5-bit pool -> (B, H, D).
 
-    Grid (slot, head block, logical page): the page table (flattened) and
-    the lengths ride in as scalar-prefetch operands and the KV BlockSpec
-    index maps read ``pages[b, k]`` to pick tile k's pool page, so each
-    grid step DMAs one page's packed payload — a gather walk over the
-    table instead of a contiguous token axis. The tile width IS the page
-    size, logical page index k supplies the positions for the length
-    mask, and unused trailing table entries (zeros -> the scratch page)
-    are fully masked exact no-ops, so the result is bitwise equal to the
-    contiguous kernel at ``block_kv=P`` on a page-multiple capacity.
+    Grid (slot, block of table entries): the page table (flattened) and
+    the lengths ride in as scalar-prefetch operands; a block is
+    :func:`_pages_per_block` pages x all KV heads, one pipelined
+    DMA per page. Only a slot's live entries — those below
+    ``ceil(length / P)`` — are read: past them no DMA is issued and no
+    page is dequantized or scored. Each page is folded as one recurrence
+    tile, in table order, and each entry past the length as a fully
+    masked tile, so the result is bitwise equal to the contiguous kernel
+    at ``block_kv=P`` on a page-multiple capacity.
     """
     B, H, D = q.shape
     assert D == d_head and kernel_compatible(k_pool, n_kv_heads, d_head)
     P = kvcache.pool_page_tokens(k_pool)
     n_tiles = pages.shape[1]
     rep = H // n_kv_heads
-    hb = heads_per_block(d_head, n_kv_heads)
-    grid = (B, n_kv_heads // hb, n_tiles)
-    assert KV_GRID_AXIS == len(grid) - 1
-
+    pb = _pages_per_block(P, n_tiles)
     qf = q.reshape(B, n_kv_heads, rep, D)
     kernel = functools.partial(_fused_paged_kernel, d_head=d_head,
-                               n_tiles=n_tiles)
+                               n_tiles=n_tiles, pages_per_block=pb)
 
-    def page(b, h, k, pt, ln):
-        return (pt[b * n_tiles + k], h, 0)
+    def page_spec(a, i):
+        """Page i of block j: table entry j*pb + i while it is live. Past
+        the slot's last live page the operand holds the last live entry it
+        fetched (the slot's last live entry if it fetched none), so the
+        pipeline sees an unchanged block and issues no DMA."""
+        def index(b, j, pt, ln):
+            n_live = _live_pages(ln, b, P, n_tiles)
+            k = j * pb + i
+            own = i + (n_live - 1 - i) // pb * pb
+            last = jnp.where(n_live > i, own, jnp.maximum(n_live - 1, 0))
+            return (pt[b * n_tiles + jnp.where(k < n_live, k, last)], 0, 0)
+        return pl.BlockSpec((1,) + a.shape[1:], index)
 
-    codes = pl.BlockSpec((1, hb * D // 2, P), page)
-    meta = pl.BlockSpec((1, hb * D // 64, P), page)
+    leaves = (k_pool["codes"], k_pool["meta"], v_pool["codes"],
+              v_pool["meta"])
+    slot = pl.BlockSpec((1, n_kv_heads, rep, D),
+                        lambda b, j, pt, ln: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, hb, rep, D), lambda b, h, k, pt, ln: (b, h, 0, 0)),
-            codes, meta, codes, meta,
-        ],
-        out_specs=pl.BlockSpec((1, hb, rep, D),
-                               lambda b, h, k, pt, ln: (b, h, 0, 0)),
-        scratch_shapes=_softmax_scratch(hb, rep, D),
+        grid=(B, pl.cdiv(n_tiles, pb)),
+        in_specs=[slot] + [page_spec(a, i) for i in range(pb)
+                           for a in leaves],
+        out_specs=slot,
+        scratch_shapes=_softmax_scratch(n_kv_heads, rep, D) + [
+            pltpu.VMEM((2, n_kv_heads * D, pb * P), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
@@ -419,7 +531,7 @@ def fused_paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((B, n_kv_heads, rep, D), jnp.float32),
         interpret=interpret,
     )(pages.astype(jnp.int32).reshape(-1), length.astype(jnp.int32), qf,
-      k_pool["codes"], k_pool["meta"], v_pool["codes"], v_pool["meta"])
+      *(leaves * pb))
     return out.reshape(B, H, D).astype(q.dtype)
 
 
@@ -440,7 +552,10 @@ def fused_paged_decode_attention_xla(
     (``pool[pages[:, k]]``) instead of slicing a contiguous token axis.
     The gathered bytes feed the same shared K-major decode and the same
     per-tile ops, so kernel (interpret) and twin agree bitwise, and both
-    agree bitwise with the contiguous paths at ``block_kv=P``.
+    agree bitwise with the contiguous paths at ``block_kv=P``. Like the
+    kernel, a slot's entries at or past ``ceil(length / P)`` contribute no
+    values (their p·V is dropped): whatever bytes they point at never
+    reach the result.
     """
     B, H, D = q.shape
     assert D == d_head
@@ -472,7 +587,8 @@ def fused_paged_decode_attention_xla(
         p = (e / l_new).astype(vblk.dtype)
         pv = jnp.einsum("bgrk,bkgd->bgrd", p, vblk,
                         preferred_element_type=jnp.float32)
-        acc_new = acc * (l * corr / l_new) + pv
+        live = (ki * P < length)[:, None, None, None]               # (B,)
+        acc_new = acc * (l * corr / l_new) + jnp.where(live, pv, 0.0)
         return (m_new, l_new, acc_new), None
 
     init = (

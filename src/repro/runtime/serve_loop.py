@@ -749,7 +749,10 @@ def serve_requests(
     ``serve.decode``, ``serve.account``, ``serve.finish``; about a
     microsecond of host time each while no profiler trace records)
     and adds to ``stats`` the counters ``prefills``, ``prefill_tokens``,
-    ``decode_chunks`` and ``decode_steps`` and, per request id,
+    ``decode_chunks`` and ``decode_steps``, ``attn_pages_walked`` (the
+    page-table entries decode attention reads: per slot and decode step,
+    those below ``ceil(length / page_tokens)``) and ``attn_pages_table``
+    (the table entries there are), and, per request id,
     ``request_times``: ``admitted``, ``first_token`` and ``finished`` in
     seconds since this call began (``time.perf_counter``), and the
     ``tokens`` its result holds.
@@ -1174,6 +1177,8 @@ def _serve_requests_paged(
     snapshot_drops = 0
     chunk_idx = 0
     prefills = prefill_tokens = 0
+    slot_pos = [0] * B                 # host copy of cache["pos"]
+    pages_walked = 0                   # table entries decode attention read
     # Page-checksum audit state: ``recorded`` maps pool page id -> the
     # byte-sum observed after the last chunk; ``dirty`` collects pages the
     # scheduler itself wrote since then (admission scatters, COW copies,
@@ -1288,6 +1293,7 @@ def _serve_requests_paged(
             del suspended[rid]
             token = token.at[b].set(snap["token"])
             cache["pos"] = cache["pos"].at[b].set(len(snap["written"]))
+            slot_pos[b] = len(snap["written"])
             done = done.at[b].set(False)
             slot_toks[b] = snap["toks"]
             slot_written[b] = snap["written"]
@@ -1352,6 +1358,7 @@ def _serve_requests_paged(
                 first = int(jax.device_get(jnp.argmax(logits, axis=-1))[0])
             token = token.at[b].set(first)
             cache["pos"] = cache["pos"].at[b].set(n_tok)
+            slot_pos[b] = n_tok
             done = done.at[b].set(eos is not None and first == eos)
             slot_toks[b] = [first]
             slot_written[b] = list(toks)
@@ -1541,6 +1548,13 @@ def _serve_requests_paged(
         t_host = time.perf_counter()
         chunk_idx += 1
         with jax.profiler.TraceAnnotation("serve.account"):
+            # 0) the pages decode attention walked: every slot's position
+            #    advanced once a step, attending over pos + 1 tokens
+            for b in range(B):
+                pages_walked += sum(
+                    min(kvcache.pages_for_tokens(slot_pos[b] + s + 1, P),
+                        maxp) for s in range(chunk))
+                slot_pos[b] += chunk
             # 1) account this chunk's KV writes (and mark their pages dirty)
             chunk_emitted = {}
             for b in range(B):
@@ -1688,6 +1702,8 @@ def _serve_requests_paged(
                 reports=reports,
                 prefills=prefills, prefill_tokens=prefill_tokens,
                 decode_chunks=chunk_idx, decode_steps=chunk_idx * chunk,
+                attn_pages_walked=pages_walked,
+                attn_pages_table=chunk_idx * chunk * B * maxp,
                 request_times=request_times,
                 **_report_counts(reports))
     return results

@@ -103,6 +103,25 @@ def test_corrupt_meta_nan_propagates_every_path(x):
     assert np.all(np.isnan(np.asarray(deq)))
 
 
+@hypothesis.given(group_batches(), st.booleans())
+def test_dequantize_km_split_is_dequantize_km_deinterleaved(x, nan_meta):
+    """The paged attention kernel's dequantize keeps a code byte's two
+    rows apart (``dequantize_km_split``); interleaved, its values are
+    ``dequantize_km``'s bit for bit, the NaN sentinel included."""
+    n = x.shape[0]
+    p = hif4.quantize_packed(jnp.asarray(x))
+    meta = p.meta
+    if nan_meta:
+        meta = (meta & jnp.uint32(0x00FFFFFF)) | jnp.uint32(0xFF << 24)
+    codes_km = jnp.asarray(np.asarray(p.codes).reshape(n * 32, 1))
+    meta_km = jnp.asarray(np.asarray(meta).reshape(n, 1))
+    full = np.asarray(hif4.dequantize_km(codes_km, meta_km,
+                                         dtype=jnp.float32))
+    even, odd = hif4.dequantize_km_split(codes_km, meta_km)
+    np.testing.assert_array_equal(np.asarray(even), full[0::2])
+    np.testing.assert_array_equal(np.asarray(odd), full[1::2])
+
+
 @hypothesis.given(group_batches(),
                   st.integers(min_value=0, max_value=31),
                   st.data())

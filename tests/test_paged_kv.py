@@ -92,16 +92,18 @@ def test_append_token_paged_matches_contiguous_append():
 # ---------------------------------------------------------------------------
 
 
-def _build_paged_case(seed=0, B=2, Hkv=4, Dh=32, P=16, maxp=3):
+def _build_paged_case(seed=0, B=2, Hkv=4, Dh=32, P=16, maxp=3, rep=3,
+                      lengths=None):
     """Random per-slot KV prefixes scattered into a shuffled page pool,
-    plus the equivalent contiguous kernel-layout cache."""
+    plus the equivalent contiguous kernel-layout cache. Every table entry
+    points at a page of quantized bytes, those past ``length`` too."""
     cap = maxp * P
-    lengths = jnp.asarray([cap - 5, P + 3][:B], jnp.int32)
+    lengths = jnp.asarray(lengths or [cap - 5, P + 3][:B], jnp.int32)
     kv_k = (jax.random.normal(jax.random.PRNGKey(seed), (B, cap, Hkv, Dh))
             * 0.3).astype(jnp.bfloat16)
     kv_v = (jax.random.normal(jax.random.PRNGKey(seed + 1),
                               (B, cap, Hkv, Dh)) * 0.3).astype(jnp.bfloat16)
-    q = (jax.random.normal(jax.random.PRNGKey(seed + 2), (B, Hkv * 3, Dh))
+    q = (jax.random.normal(jax.random.PRNGKey(seed + 2), (B, Hkv * rep, Dh))
          * 0.3).astype(jnp.bfloat16)
 
     def contiguous(kv):
@@ -113,7 +115,8 @@ def _build_paged_case(seed=0, B=2, Hkv=4, Dh=32, P=16, maxp=3):
     # ids (page 0 = scratch stays zero)
     n_pages = B * maxp + 1
     pool = kvcache.init_page_pool(1, Hkv, Dh, n_pages, P)
-    ids = [[2, 5, 1], [6, 3, 4]]
+    ids = (np.random.default_rng(seed).permutation(n_pages - 1) + 1
+           ).reshape(B, maxp)
     for b in range(B):
         pk = kvcache.split_pages(
             {key: a[b][None, None] for key, a in kc.items()}, P)
@@ -128,11 +131,22 @@ def _build_paged_case(seed=0, B=2, Hkv=4, Dh=32, P=16, maxp=3):
     return q, (kc, vc), (k_pool, v_pool), table, lengths, (Hkv, Dh, P)
 
 
-def test_paged_attention_bitwise_vs_contiguous():
+# maxp 3 is one block of 3 pages; maxp 10 walks blocks of 8 pages, and a
+# 10-entry table is not a multiple of the block
+@pytest.mark.parametrize("case", [
+    dict(),                                       # GQA rep 3, d_head 32
+    dict(maxp=10, lengths=[8 * 16, 3 * 16]),      # block / page boundary
+    dict(maxp=10, lengths=[1, 10 * 16]),          # one token / full table
+    dict(maxp=10, Hkv=2, Dh=64, rep=1, lengths=[5 * 16 + 7, 9 * 16 + 1]),
+], ids=["gqa-dh32", "block-and-page-boundary", "one-token-full-table",
+        "mha-dh64"])
+def test_paged_attention_bitwise_vs_contiguous(case):
     """All four executions — paged kernel (interpret), paged XLA twin,
     contiguous kernel at block_kv=P, contiguous XLA twin — produce the SAME
-    bits: the page gather only reorders DMA, never arithmetic."""
-    q, (kc, vc), (kp, vp), table, lengths, (Hkv, Dh, P) = _build_paged_case()
+    bits: the page gather and the block walk only reorder DMA, never
+    arithmetic."""
+    q, (kc, vc), (kp, vp), table, lengths, (Hkv, Dh, P) = _build_paged_case(
+        **case)
 
     cont_kernel = fa.fused_decode_attention(
         q, kc, vc, lengths, n_kv_heads=Hkv, d_head=Dh, block_kv=P,
@@ -149,18 +163,35 @@ def test_paged_attention_bitwise_vs_contiguous():
         np.testing.assert_array_equal(np.asarray(got), ref)
 
 
-def test_paged_attention_trailing_scratch_pages_are_noops():
-    """Table rows longer than the live prefix point at the zero scratch
-    page; those fully masked tiles must not change a single bit."""
-    q, _, (kp, vp), table, lengths, (Hkv, Dh, P) = _build_paged_case()
-    # slot 1 holds P+3 tokens: logical page 2 is entirely masked — swapping
-    # its table entry for the scratch page is invisible
-    alt = table.at[1, 2].set(0)
-    a = fa.fused_paged_decode_attention_xla(q, kp, vp, table, lengths,
-                                            Hkv, Dh)
-    b = fa.fused_paged_decode_attention_xla(q, kp, vp, alt, lengths,
-                                            Hkv, Dh)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+@pytest.mark.parametrize("dead_page", ["scratch", "nan_meta"])
+def test_paged_attention_trailing_scratch_pages_are_noops(dead_page):
+    """Table entries past a slot's live pages (``ceil(length / P)``) never
+    change a bit, whatever page they point at: the zero scratch page, or a
+    page whose meta is E6M2 0xFF (NaN), which would turn the result NaN
+    if it were read."""
+    q, _, (kp, vp), table, lengths, (Hkv, Dh, P) = _build_paged_case(
+        maxp=10, lengths=[8 * 16 + 5, 16 + 3])
+    if dead_page == "scratch":
+        page = 0
+    else:                           # one more pool page, every scale NaN
+        def grow(pool):
+            extra = {key: jnp.zeros_like(a[:1]) for key, a in pool.items()}
+            extra["meta"] = jnp.full_like(extra["meta"], 0xFF000000)
+            return {key: jnp.concatenate([a, extra[key]])
+                    for key, a in pool.items()}
+        kp, vp = grow(kp), grow(vp)
+        page = kp["meta"].shape[0] - 1
+    live = -(-np.asarray(lengths) // P)
+    dead = np.arange(table.shape[1])[None, :] >= live[:, None]
+    alt = jnp.where(jnp.asarray(dead), page, table)
+    for run in (
+            lambda t: fa.fused_paged_decode_attention_xla(
+                q, kp, vp, t, lengths, Hkv, Dh),
+            lambda t: fa.fused_paged_decode_attention(
+                q, kp, vp, t, lengths, n_kv_heads=Hkv, d_head=Dh,
+                interpret=True)):
+        np.testing.assert_array_equal(np.asarray(run(alt)),
+                                      np.asarray(run(table)))
 
 
 # ---------------------------------------------------------------------------
