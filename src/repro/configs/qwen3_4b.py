@@ -1,6 +1,7 @@
 """qwen3-4b [dense] — 36L d_model=2560 32H (GQA kv=8) d_ff=9728 vocab=151936.
 
-Features: qk_norm, GQA.  [hf:Qwen/Qwen3-8B; hf]
+Features: qk_norm, GQA, d_head 128 decoupled from d_model, tied embedding
+and head, rms_norm_eps 1e-6.  [hf:Qwen/Qwen3-4B; hf]
 """
 from repro.configs.base import ArchConfig, AttnConfig, register_arch
 
@@ -21,6 +22,8 @@ CONFIG = register_arch(
             qk_norm=True,
             rope_theta=1_000_000.0,
         ),
-        source="hf:Qwen/Qwen3-8B; hf",
+        tie_embeddings=True,
+        norm_eps=1e-6,
+        source="hf:Qwen/Qwen3-4B; hf",
     )
 )

@@ -752,7 +752,10 @@ def serve_requests(
     ``decode_chunks`` and ``decode_steps``, ``attn_pages_walked`` (the
     page-table entries decode attention reads: per slot and decode step,
     those below ``ceil(length / page_tokens)``) and ``attn_pages_table``
-    (the table entries there are), and, per request id,
+    (the table entries there are), ``decode_slot_steps_live`` (the
+    slot-steps of decode in which a slot holds a request still inside its
+    token budget) and ``decode_kv_tokens`` (the cached tokens those
+    slot-steps attend over), and, per request id,
     ``request_times``: ``admitted``, ``first_token`` and ``finished`` in
     seconds since this call began (``time.perf_counter``), and the
     ``tokens`` its result holds.
@@ -1179,6 +1182,7 @@ def _serve_requests_paged(
     prefills = prefill_tokens = 0
     slot_pos = [0] * B                 # host copy of cache["pos"]
     pages_walked = 0                   # table entries decode attention read
+    slot_steps_live = kv_tokens = 0    # decode slot-steps a request keeps
     # Page-checksum audit state: ``recorded`` maps pool page id -> the
     # byte-sum observed after the last chunk; ``dirty`` collects pages the
     # scheduler itself wrote since then (admission scatters, COW copies,
@@ -1549,11 +1553,17 @@ def _serve_requests_paged(
         chunk_idx += 1
         with jax.profiler.TraceAnnotation("serve.account"):
             # 0) the pages decode attention walked: every slot's position
-            #    advanced once a step, attending over pos + 1 tokens
+            #    advanced once a step, attending over pos + 1 tokens; and
+            #    the steps of a request still inside its token budget,
+            #    with the tokens those steps attend over
             for b in range(B):
                 pages_walked += sum(
                     min(kvcache.pages_for_tokens(slot_pos[b] + s + 1, P),
                         maxp) for s in range(chunk))
+                if slot_req[b] is not None:
+                    live = max(0, min(chunk, budget - len(slot_toks[b])))
+                    slot_steps_live += live
+                    kv_tokens += live * (slot_pos[b] + 1) + live * (live - 1) // 2
                 slot_pos[b] += chunk
             # 1) account this chunk's KV writes (and mark their pages dirty)
             chunk_emitted = {}
@@ -1704,6 +1714,8 @@ def _serve_requests_paged(
                 decode_chunks=chunk_idx, decode_steps=chunk_idx * chunk,
                 attn_pages_walked=pages_walked,
                 attn_pages_table=chunk_idx * chunk * B * maxp,
+                decode_slot_steps_live=slot_steps_live,
+                decode_kv_tokens=kv_tokens,
                 request_times=request_times,
                 **_report_counts(reports))
     return results

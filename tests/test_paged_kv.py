@@ -138,8 +138,9 @@ def _build_paged_case(seed=0, B=2, Hkv=4, Dh=32, P=16, maxp=3, rep=3,
     dict(maxp=10, lengths=[8 * 16, 3 * 16]),      # block / page boundary
     dict(maxp=10, lengths=[1, 10 * 16]),          # one token / full table
     dict(maxp=10, Hkv=2, Dh=64, rep=1, lengths=[5 * 16 + 7, 9 * 16 + 1]),
+    dict(maxp=4, Hkv=8, Dh=128, rep=4, lengths=[3 * 16 + 5, 4 * 16]),  # qwen3
 ], ids=["gqa-dh32", "block-and-page-boundary", "one-token-full-table",
-        "mha-dh64"])
+        "mha-dh64", "gqa-hkv8-rep4-dh128"])
 def test_paged_attention_bitwise_vs_contiguous(case):
     """All four executions — paged kernel (interpret), paged XLA twin,
     contiguous kernel at block_kv=P, contiguous XLA twin — produce the SAME
