@@ -127,13 +127,31 @@ def check_dispatch(check, cfg, ctx, serving, decode_step_text: str):
           f"{n} tpu_custom_call sites")
 
 
+def _decode_in_kernel(codes, meta):
+    """``hif4.absorbed_int_km`` of whole (K/2, N) / (K/64, N) buffers, run
+    as one Pallas kernel on the chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from repro.core import hif4
+
+    def kernel(c_ref, m_ref, i_ref, s_ref):
+        i_ref[...], s_ref[...] = hif4.absorbed_int_km(c_ref[...], m_ref[...])
+
+    half, n = codes.shape
+    return pl.pallas_call(kernel, out_shape=(
+        jax.ShapeDtypeStruct((2 * half, n), jnp.int8),
+        jax.ShapeDtypeStruct(meta.shape, jnp.float32)))(codes, meta)
+
+
 def check_kernels(check, cfg, serving, key):
     """Each serve-path kernel against its XLA twin, on the chip."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core import kvcache
+    from repro.core import hif4, kvcache
     from repro.core.qlinear import PackedW
     from repro.kernels import fused_attention as fa
     from repro.kernels.fused_matmul import (absorbed_activation,
@@ -153,6 +171,15 @@ def check_kernels(check, cfg, serving, key):
 
     # fused packed matmul on real packed weights (layer 0): both widths
     layer = jax.tree_util.tree_map(lambda b: b[0], serving["blocks"]["mlp"])
+    # its word-level unpack reads code rows in the order pltpu.bitcast
+    # packs them: the chip's kernel must decode the XLA twin's bits
+    codes, meta = layer["wu"].kernel_operands()
+    codes, meta = codes[:256, :1024], meta[:8, :1024]
+    in_kernel = _decode_in_kernel(codes, meta)
+    in_xla = hif4.absorbed_int_km(codes, meta)
+    check("absorbed_int_km in a kernel == in XLA (bitwise)",
+          all(np.array_equal(np.asarray(a), np.asarray(b))
+              for a, b in zip(in_kernel, in_xla)))
     for name in ("wu", "wo"):
         w = layer[name]
         assert isinstance(w, PackedW), (name, type(w))

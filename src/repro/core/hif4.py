@@ -18,6 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rounding as R
 
@@ -25,7 +26,7 @@ GROUP_SIZE = 64
 N_E1_8 = 8    # level-2 micro-exponents: one per 8 elements
 N_E1_16 = 16  # level-3 micro-exponents: one per 4 elements
 BITS_PER_VALUE = 4.5
-# E6M2 code 0xFF decodes to NaN on every path (expand_meta_km below,
+# E6M2 code 0xFF decodes to NaN on every path (_meta_fields below,
 # rounding.decode_e6m2). Algorithm 1 NEVER produces it, so its presence in
 # packed metadata is definitionally corruption — the health sentinel the
 # serving guard (repro.runtime.guard) counts on packed KV pages.
@@ -107,7 +108,7 @@ def meta_nan_mask(meta: jnp.ndarray) -> jnp.ndarray:
     """Elementwise True where a packed meta word carries the E6M2 NaN
     sentinel (scale byte == :data:`META_NAN`). Any True is corruption:
     Algorithm 1 never emits 0xFF, and every decode path turns it into NaN
-    (:func:`expand_meta_km`), so this mask is the cheap integrity probe
+    (:func:`_meta_fields`), so this mask is the cheap integrity probe
     health audits reduce over."""
     return (meta >> 24) == jnp.uint32(META_NAN)
 
@@ -182,7 +183,8 @@ def _micro_shift(w8, w16, r):
 
 def _meta_shift_scale(meta_km: jnp.ndarray):
     """(bg, bn) uint32 metadata -> (shift (bg, 64, bn) int32, scale
-    (bg, bn) f32): the grouped form :func:`expand_meta_km` flattens."""
+    (bg, bn) f32): the per-element micro-exponent sum E1_8 + E1_16 and
+    the group scale E6M2 / 4."""
     bg, bn = meta_km.shape
     w8, w16, scale = _meta_fields(meta_km)
     r = jax.lax.broadcasted_iota(jnp.int32, (bg, GROUP_SIZE, bn), 1)
@@ -209,28 +211,58 @@ def _meta_fields(meta_km: jnp.ndarray):
     return w8, w16, scale
 
 
-def expand_meta_km(meta_km: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(bg, bn) uint32 K-major group metadata -> (shift, scale).
+# Word-level ("SWAR") decode for the fused matmul. A 16-bit view of a
+# code tile (``pltpu.bitcast``: code rows 2r and 2r+1, low byte first)
+# holds contraction rows 4r..4r+3, one nibble each. Widened to int32 and
+# spread one nibble per byte, each word decodes with a handful of integer
+# ops for its four values, and its bytes, viewed as int8, are those rows
+# in contraction order: no value is widened alone, no row is shuffled.
+# The four rows of a word share one E1_16 bit (r) and one E1_8 bit (r/2),
+# so one shift serves the whole word; |q| <= 7 << 2 = 28 never carries
+# into the next byte.
+_WORD_ROWS = GROUP_SIZE // 4        # int32 word rows of one group
+_MSB = 0x80808080 - (1 << 32)       # bit 7 of each byte, as an int32
 
-    ``shift`` (bg*64, bn) int32 is the per-element micro-exponent sum
-    E1_8 + E1_16; ``scale`` (bg, bn) f32 is the absorbed group scale
-    E6M2 / 4 (bitwise identical to ``decode_e6m2(meta>>24) * 0.25`` but
-    built by exponent bitcast on the small per-group tile only, no LUT)."""
-    shift, scale = _meta_shift_scale(meta_km)
-    bg, _, bn = shift.shape
-    return shift.reshape(bg * GROUP_SIZE, bn), scale
+
+def _bitcast_rows(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``pltpu.bitcast``: consecutive rows of ``x`` packed into (or
+    unpacked from) wider words, the first row in the low bits. Mosaic
+    lowers it inside a kernel and XLA under ``jit``; it has no eager
+    rule, so a concrete array (``jax.disable_jit``) takes the reshape and
+    ``bitcast_convert_type`` it lowers to."""
+    if isinstance(x, jax.core.Tracer):
+        return pltpu.bitcast(x, dtype)
+    ratio = jnp.dtype(dtype).itemsize / x.dtype.itemsize
+    *lead, m, n = x.shape
+    if ratio > 1:
+        x = x.reshape(*lead, m // int(ratio), int(ratio), n)
+        return jax.lax.bitcast_convert_type(jnp.swapaxes(x, -1, -2), dtype)
+    y = jnp.swapaxes(jax.lax.bitcast_convert_type(x, dtype), -1, -2)
+    return y.reshape(*lead, int(m / ratio), n)
 
 
 def absorbed_int_km(codes_km: jnp.ndarray, meta_km: jnp.ndarray):
     """K-major packed tile -> (ints (bk, bn) int8, scale (bk/64, bn) f32).
 
     The §III.B absorbed-shift operand (micro-exponents folded in as left
-    shifts, |q| <= 28), produced directly from the 4.5-bit payload without
-    materializing values: bitwise identical to
-    ``to_absorbed_int(unpack_groups(...))`` re-laid out K-major."""
-    quarters = expand_codes_km(codes_km)
-    shift, scale = expand_meta_km(meta_km)
-    return (quarters << shift).astype(jnp.int8), scale
+    shifts, |q| <= 28), produced directly from the 4.5-bit payload four
+    values per int32 op: bitwise identical to
+    ``to_absorbed_int(unpack_groups(...))`` re-laid out K-major, with
+    ``scale`` = E6M2 / 4 (NaN for the 0xFF record)."""
+    bg, bn = meta_km.shape
+    half = _bitcast_rows(codes_km, jnp.int16)              # (bk/4, bn)
+    x = half.astype(jnp.int32).reshape(bg, _WORD_ROWS, bn) & 0xFFFF
+    x = (x | (x << 8)) & 0x00FF00FF     # code row 2r in byte 0, 2r+1 in 2
+    x = x | (x << 4)                    # row 4r+b's code: low nibble, byte b
+    w8, w16, scale = _meta_fields(meta_km)
+    r = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = (((w8[:, None, :] >> (r >> 1)) & 1)
+             + ((w16[:, None, :] >> r) & 1))
+    mag = (x & 0x07070707) << shift
+    sign = (x >> 3) & 0x01010101
+    # per byte: q for sign 0, 256 - q (0 for q = 0) for sign 1, no borrow
+    ints = ((mag | _MSB) - sign) ^ (_MSB - sign)
+    return _bitcast_rows(ints.reshape(bg * _WORD_ROWS, bn), jnp.int8), scale
 
 
 def dequantize_km(codes_km: jnp.ndarray, meta_km: jnp.ndarray,

@@ -61,9 +61,11 @@ _DECODE_M_MAX = 32
 _LANE = 128                     # lane tile of every dtype
 _INT8_ROWS = 32                 # sublane tile of int8/uint8
 _META_ROWS = 8                  # sublane tile of the 32-bit scales/meta
-# Cap on bk*bn: the unpacked weight tile and its int32 temporaries live
-# in VMEM, so a full-K tile (no 512-multiple divides K) narrows N.
-_TILE_ELEMS_MAX = 512 * 1024
+# Cap on bk*bn: the unpacked weight tile and its temporaries live in
+# VMEM. The fused matmul's word-level unpack keeps four values in each
+# int32 temporary (``hif4.absorbed_int_km``): 2 M values take the VMEM
+# of 512 K int32s.
+_TILE_ELEMS_MAX = 2048 * 1024
 # Scoped VMEM the matmul kernels may use (v5e has 128 MiB per core).
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
@@ -83,13 +85,18 @@ def select_block_sizes(M: int, N: int, K: int) -> tuple[int, int, int]:
     """(bm, bn, bk) per execution regime.
 
     decode (M <= 32): M doesn't tile — take all of it — and the weight is
-    the whole HBM traffic, so deep-K / wide-N tiles maximize payload per
-    grid step (fewer revisits, better DMA pipelining).
+    the whole HBM traffic, so a deep-K tile as wide in N as the VMEM cap
+    allows maximizes payload per grid step: the fixed cost of a step
+    (about 0.35 us) stays small against its unpack and DMA.
     prefill (M large): square-ish 256/256/512 MXU tiles, the classic
     compute-bound shape.
+
+    ``bk`` sets how the f32 sum over a row's 64-groups associates (one
+    partial sum per K step), so it stays as chosen here; ``bn`` only
+    splits independent output columns.
     """
     if M <= _DECODE_M_MAX:
-        bm, want_n, want_k = M, 512, 1024
+        bm, want_n, want_k = M, N, 1024
     else:
         bm, want_n, want_k = _aligned_block(M, 256, _INT8_ROWS), 256, 512
     bk = _aligned_block(K, want_k, GROUP * _META_ROWS)

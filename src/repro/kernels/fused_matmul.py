@@ -14,6 +14,21 @@ the tile in one batched MXU ``dot_general``. HBM reads per output tile are
 the packed payload plus the activation tile — no (K, N)-sized intermediate
 ever exists in HBM.
 
+How a tile decodes: the (bk/2, bn) code bytes are viewed as (bk/4, bn)
+16-bit words (``pltpu.bitcast``: code rows 2r and 2r+1, low byte first),
+each widened to one int32 word that holds the four codes of contraction
+rows 4r..4r+3. Spread one code per byte, the word decodes with integer
+ops that each serve four values: magnitude, one left shift by the
+micro-exponent sum the four rows share (E1_16 bit r plus E1_8 bit r/2),
+and two's complement per byte. Viewed as int8, the words are the tile's
+rows in contraction order, so the activation meets the weight in its own
+order and no row is shuffled. The stored bytes are the same as ever.
+
+Decode tiles (M <= 32) take a K tile of up to 1024 — it fixes how the
+f32 sum over 64-groups associates — and widen N up to a VMEM budget of
+2 M values a step, so the per-step cost does not dominate at
+Qwen3-4B's widths (``bfp_matmul.select_block_sizes``).
+
 Two executions of the same contraction:
 
 * :func:`fused_packed_matmul` — the Pallas kernel (TPU; ``interpret=True``

@@ -22,6 +22,7 @@ from repro.core import engine, hif4
 from repro.core.qlinear import PackedW, QuantConfig
 from repro.kernels import ref
 from repro.kernels.bfp_matmul import (
+    _TILE_ELEMS_MAX,
     GROUP,
     K_GRID_AXIS,
     bfp_matmul_quantized,
@@ -85,7 +86,8 @@ def test_expand_meta_km_scale_parity_all_codes():
     from repro.core import rounding as R
 
     codes = jnp.arange(256, dtype=jnp.uint32)
-    _, scale = hif4.expand_meta_km((codes << 24).reshape(1, -1))
+    _, scale = hif4.absorbed_int_km(jnp.zeros((32, 256), jnp.uint8),
+                                    (codes << 24).reshape(1, -1))
     ref_scale = R.decode_e6m2(codes.astype(jnp.uint8)) * 0.25
     np.testing.assert_array_equal(np.asarray(scale)[0],
                                   np.asarray(ref_scale))
@@ -102,6 +104,57 @@ def test_absorbed_int_km_matches_unpack_path():
                                   np.asarray(ints_ref.reshape(96, 256).T))
     np.testing.assert_array_equal(
         np.asarray(scale), np.asarray(scale_ref.astype(jnp.float32).T))
+
+
+def _artifact_reference(codes_km, meta_km):
+    """``to_absorbed_int(unpack_groups(...))`` of K-major buffers, laid out
+    K-major again: (ints (K, N) int8, scale (K/64, N) f32)."""
+    half, n = codes_km.shape
+    g = meta_km.shape[0]
+    codes = jnp.transpose(codes_km).reshape(n, g, GROUP // 2)
+    ints, scale = hif4.to_absorbed_int(hif4.unpack_groups(
+        hif4.HiF4Packed(codes, jnp.transpose(meta_km))))
+    return (jnp.transpose(ints.reshape(n, 2 * half)),
+            jnp.transpose(scale.astype(jnp.float32)))
+
+
+def _assert_word_decode_matches(codes_km, meta_km):
+    """The word-level decode == the artifact path, bit for bit (NaN
+    scales included)."""
+    ints_ref, scale_ref = _artifact_reference(codes_km, meta_km)
+    ints, scale = hif4.absorbed_int_km(codes_km, meta_km)
+    np.testing.assert_array_equal(np.asarray(ints), np.asarray(ints_ref))
+    np.testing.assert_array_equal(np.asarray(scale), np.asarray(scale_ref))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_word_decode_random_bytes(seed):
+    """Arbitrary code bytes — -0 (code 8) and every sign/magnitude pair
+    included — and arbitrary 32-bit records, NaN scales among them."""
+    rng = np.random.default_rng(seed)
+    k, n = 512, 384
+    codes_km = jnp.asarray(rng.integers(0, 256, (k // 2, n), np.uint8))
+    meta_km = jnp.asarray(rng.integers(0, 2 ** 32, (k // GROUP, n),
+                                       np.uint32))
+    _assert_word_decode_matches(codes_km, meta_km)
+
+
+def test_word_decode_every_micro_exponent_word():
+    """Every E1_16 word (65536 columns) and, across them, every E1_8 word
+    and every E6M2 code — 0xFF, the NaN record, too — against the
+    artifact path. The codes hold every byte in every code row."""
+    n = 1 << 16
+    col = np.arange(n, dtype=np.uint32)
+    w16 = col
+    w8 = (col ^ (col >> 8)) & 0xFF
+    e6m2 = (col * 37 + (col >> 8)) & 0xFF
+    meta_km = jnp.asarray(((e6m2 << 24) | (w8 << 16) | w16)[None, :])
+    rows = np.arange(GROUP // 2, dtype=np.uint32)[:, None]
+    codes_km = jnp.asarray(((col[None, :] + 97 * rows) & 0xFF).astype(
+        np.uint8))
+    assert len(set(np.asarray(meta_km >> 24)[0].tolist())) == 256
+    assert len(set(np.asarray(meta_km >> 16 & 0xFF)[0].tolist())) == 256
+    _assert_word_decode_matches(codes_km, meta_km)
 
 
 # ---------------------------------------------------------------------------
@@ -131,6 +184,57 @@ def test_fused_kernel_bit_exact(m, k, n):
     a_deq = ref.hif4_dequantize_ref(ai, asc)
     want = np.asarray(a_deq) @ np.asarray(pw.dequantize().astype(jnp.float32))
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+
+
+def _twin_by_k_steps(ai, asc, codes_km, meta_km, bk):
+    """The XLA twin over each K tile of ``bk``, summed in the kernel's
+    order (zeros, then += one tile's groups per K step)."""
+    m, k = ai.shape
+    acc = jnp.zeros((m, codes_km.shape[1]), jnp.float32)
+    for k0 in range(0, k, bk):
+        g0, g1 = k0 // GROUP, (k0 + bk) // GROUP
+        acc = acc + fused_packed_matmul_xla(
+            ai[:, k0:k0 + bk], asc[:, g0:g1],
+            codes_km[k0 // 2:(k0 + bk) // 2], meta_km[g0:g1])
+    return acc
+
+
+# decode M = 1, 8, 16 and a prefill M; K = 2560 and 9728 (Qwen3-4B's d_model
+# and d_ff, which 1024 does not divide) at their real depth, N cut for CPU time
+DEFAULT_TILE_SHAPES = [
+    (1, 1024, 256),
+    (8, 1024, 384),
+    (16, 2560, 256),
+    (16, 9728, 128),
+    (96, 1024, 256),
+]
+
+
+@pytest.mark.parametrize("m,k,n", DEFAULT_TILE_SHAPES)
+def test_fused_kernel_default_tiles_bit_exact(m, k, n):
+    """At the tiles the chip would run (``select_block_sizes``), the
+    kernel equals the twin taken K tile by K tile, bit for bit."""
+    _, pw = _packed(k, n, seed=k + m)
+    _, (ai, asc) = _activation(m, k, seed=m)
+    codes_km, meta_km = pw.kernel_operands()
+    _, _, bk = select_block_sizes(m, n, k)
+    got = fused_packed_matmul(ai, asc, codes_km, meta_km, interpret=True)
+    want = _twin_by_k_steps(ai, asc, codes_km, meta_km, bk)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("m,k,n,bk,bn", [
+    (8, 512, 96, 128, 96),        # four K steps
+    (16, 1536, 256, 512, 128),    # three K steps, two N tiles
+])
+def test_fused_kernel_multi_k_step_bit_exact(m, k, n, bk, bn):
+    _, pw = _packed(k, n, seed=3)
+    _, (ai, asc) = _activation(m, k, seed=4)
+    codes_km, meta_km = pw.kernel_operands()
+    got = fused_packed_matmul(ai, asc, codes_km, meta_km, block_k=bk,
+                              block_n=bn, interpret=True)
+    want = _twin_by_k_steps(ai, asc, codes_km, meta_km, bk)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_fused_kernel_multi_k_step():
@@ -240,6 +344,13 @@ def test_packed_dispatch_info():
     kernel = engine.packed_dispatch_info(q, pw, decode_m=4, prefill_m=128,
                                          interpret=False)
     assert kernel["fused"] and kernel["decode_blocks"] == (4, 96, 128)
+    # qwen1.5-0.5b's MLP up projection: the decode tile spans 1408 of the
+    # 2816 columns at the full 1024-deep K (two grid steps, not eleven)
+    _, up = _packed(1024, 2816)
+    kernel = engine.packed_dispatch_info(q, up, decode_m=1, prefill_m=512,
+                                         interpret=False)
+    assert kernel["decode_blocks"] == (1, 1408, 1024)
+    assert kernel["prefill_blocks"] == (256, 256, 512)
     off = engine.packed_dispatch_info(
         QuantConfig(fmt="hif4", impl="qdq"), pw, decode_m=4, prefill_m=128)
     assert not off["fused"]
@@ -251,9 +362,18 @@ def test_packed_dispatch_info():
 
 
 def test_select_block_sizes_regimes():
-    # decode: whole M, wide N, deep K
+    # decode: whole M, deep K, as wide in N as the VMEM cap allows
     bm, bn, bk = select_block_sizes(4, 1024, 2048)
-    assert bm == 4 and bn == 512 and bk == 1024
+    assert bm == 4 and bn == 1024 and bk == 1024
+    # Qwen3-4B's widths: K tiles of 512 (1024 divides neither 2560 nor
+    # 9728), N up to the 2 M-value cap: 20 and 19 grid steps, not 95
+    assert select_block_sizes(16, 9728, 2560) == (16, 2432, 512)
+    assert select_block_sizes(16, 2560, 9728) == (16, 2560, 512)
+    # a full-K tile (no 512-multiple divides 2816) narrows N to the cap
+    assert select_block_sizes(1, 1024, 2816) == (1, 512, 2816)
+    for m, n, k in [(16, 9728, 2560), (1, 1024, 2816), (8, 4096, 2560)]:
+        _, bn, bk = select_block_sizes(m, n, k)
+        assert bn * bk <= _TILE_ELEMS_MAX
     # prefill: square-ish MXU tiles
     bm, bn, bk = select_block_sizes(512, 1024, 2048)
     assert bm == 256 and bn == 256 and bk == 512

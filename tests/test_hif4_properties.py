@@ -80,7 +80,7 @@ def test_pack_unpack_is_bitwise_idempotent(x):
 @hypothesis.given(group_batches())
 def test_corrupt_meta_nan_propagates_every_path(x):
     """E6M2 code 0xFF decodes to NaN on EVERY path — artifact-layout
-    unpack, packed dequantize, and all three K-major kernel-tile helpers.
+    unpack, packed dequantize, and both K-major kernel-tile helpers.
     Corrupted metadata must poison the whole group loudly, never decode
     to silently-wrong values."""
     n = x.shape[0]
@@ -95,8 +95,6 @@ def test_corrupt_meta_nan_propagates_every_path(x):
     # K-major kernel-tile layout: one column per group row
     codes_km = jnp.asarray(np.asarray(p.codes).reshape(n * 32, 1))
     meta_km = jnp.asarray(np.asarray(bad_meta).reshape(n, 1))
-    _, scale = hif4.expand_meta_km(meta_km)
-    assert np.all(np.isnan(np.asarray(scale)))
     _, scale_abs = hif4.absorbed_int_km(codes_km, meta_km)
     assert np.all(np.isnan(np.asarray(scale_abs)))
     deq = hif4.dequantize_km(codes_km, meta_km, dtype=jnp.float32)
