@@ -1,4 +1,4 @@
-"""Benchmark suite driver — one module per paper table/figure.
+"""Paper-table driver — one module per paper table/figure, then the docs lint.
 
     PYTHONPATH=src python -m benchmarks.run [--skip-llm]
 
@@ -6,158 +6,16 @@
   quant_error      -> Fig. 3         (Gaussian MSE sweep, 1 : 1.32 : 1.89)
   dot_product      -> §III.B / Fig.4 (fixed-point flow + multiplier counts)
   llm_accuracy     -> Tables III-V   (tiny-LM proxy incl. the NVFP4 crash)
-  serve_throughput -> deployment     (scan-decode tok/s per impl,
-                                      decode-step latency per kv_format,
-                                      paged scheduler gated >= 2x slot
-                                      admission at equal KV bytes bitwise
-                                      vs solo, prefill latency, 4.5-bit
-                                      weight + KV-cache residency
-                                      -> BENCH_serve.json; the two 0.9x
-                                      decode ratio gates moved to the
-                                      scenario matrix)
-  roofline         -> §Roofline      (aggregates experiments/dryrun/*.json)
-  check_matrix_gates -> perf gates   (BENCH_matrix.json scenario matrix:
-                                      cell coverage, expected dispatch,
-                                      no silent hif4->bf16 fallback, the
-                                      packed/qdq + hif4/bf16 decode
-                                      ratios — benchmarks/matrix.py is
-                                      the single perf-regression surface)
   check_docs       -> repo lint      (README/docs must not reference dead
-                                      symbols, files, or gate names)
+                                      symbols, files, presets, statuses
+                                      or fault classes)
+
+None of these measures speed. Serving speed is measured on the chip by
+``bench/run.py``, against the contract in ``BENCHMARK.json``.
 """
 import argparse
-import json
-import os
 import sys
 import time
-
-
-def check_matrix_gates(path=None):
-    """The scenario matrix (benchmarks/matrix.py) is THE perf-regression
-    surface: every gate — cell coverage across all families/impls,
-    per-cell expected-dispatch assertions, no silent hif4->bf16 fallback,
-    and the packed>=0.9x-qdq / hif4-KV>=0.9x-bf16-KV decode ratios that
-    used to live as hand-coded asserts in serve_throughput — is validated
-    here against the committed BENCH_matrix.json, failing loudly (never
-    skipping) on a missing field, a failed assertion, or a regressed
-    ratio. Re-measurement against the stored trajectory is matrix.py's
-    `--cells` runs; this check is the static side every CI run pays.
-    """
-    from benchmarks import matrix
-
-    path = path or os.path.join(os.path.dirname(__file__),
-                                "BENCH_matrix.json")
-    assert os.path.exists(path), (
-        f"{os.path.basename(path)} missing — run "
-        f"`python -m benchmarks.matrix --cells all --update`")
-    with open(path) as f:
-        record = json.load(f)
-    matrix.check(record)
-    cells = record["cells"]
-    gates = {g["name"]: g["value"] for g in record["ratio_gates"]}
-    print(f"[matrix gates] {len(cells)} cells "
-          f"({len({c['family'] for c in cells})} families, "
-          f"{len({c['impl'] for c in cells})} impls) on "
-          f"{record['backend']}; all dispatch assertions passed; " +
-          ", ".join(f"{k} = {v}x" for k, v in gates.items()))
-
-
-def check_serve_gates():
-    """BENCH_serve.json must still RECORD the serving comparisons — the
-    per-impl decode ratio and per-kv_format decode-step ratio fields, the
-    mixed-policy rows, and the paged-scheduler row. A benchmark refactor
-    that silently drops a field must fail here loudly, not skip. The 0.9x
-    THRESHOLDS on the two decode ratios moved to the scenario matrix
-    (check_matrix_gates); the paged admission/bitwise gate stays here. A
-    null value is accepted ONLY when the recorded sweep demonstrably
-    lacks one side of the comparison (a narrowed `--impl`/`--kv-format`
-    run) — null with both sides present means the field was skipped,
-    which is exactly the failure this check exists for.
-    """
-    path = os.path.join(os.path.dirname(__file__), "BENCH_serve.json")
-    assert os.path.exists(path), (
-        "benchmarks/BENCH_serve.json missing — run benchmarks.serve_throughput")
-    with open(path) as f:
-        record = json.load(f)
-    rows = record.get("results", [])
-    impls = {r.get("impl") for r in rows}
-    packed_kvs = {r.get("kv_format") for r in rows if r.get("impl") == "packed"}
-    both_sides = {
-        "packed_over_qdq_decode": {"packed", "qdq"} <= impls,
-        "hif4_over_bf16_kv_decode": {"bf16", "hif4"} <= packed_kvs,
-    }
-    shown = {}
-    for gate, covered in both_sides.items():
-        assert gate in record, (
-            f"BENCH_serve.json lacks the `{gate}` gate — serve_throughput "
-            f"must record (and assert) it, not skip it")
-        if record[gate] is None:
-            assert not covered, (
-                f"BENCH_serve.json has `{gate}` = null although the sweep "
-                f"covered both sides of the comparison — the gate was "
-                f"skipped, not inapplicable")
-            shown[gate] = "n/a (narrowed sweep)"
-        else:
-            shown[gate] = f"{record[gate]}x"
-    print(f"[serve gates] packed/qdq decode = "
-          f"{shown['packed_over_qdq_decode']}, hif4/bf16 KV decode = "
-          f"{shown['hif4_over_bf16_kv_decode']}")
-
-    # mixed-policy rows (QuantPolicy presets): required whenever the sweep
-    # exercised the packed impl — a benchmark refactor that silently drops
-    # the per-site-policy comparison must fail here, not vanish
-    assert "policy_rows" in record, (
-        "BENCH_serve.json lacks `policy_rows` — serve_throughput must "
-        "record the mixed-policy (uniform:hif4 vs paper-iv) comparison")
-    rows = record["policy_rows"]
-    if rows is None:
-        assert "packed" not in impls, (
-            "BENCH_serve.json has `policy_rows` = null although the sweep "
-            "covered the packed impl — the policy comparison was skipped, "
-            "not inapplicable")
-        print("[policy rows] n/a (narrowed sweep)")
-    else:
-        for required in ("uniform:hif4", "paper-iv"):
-            assert required in rows, (
-                f"policy_rows lacks the `{required}` row — the mixed-policy "
-                f"comparison must cover it")
-            assert rows[required].get("decode_step_ms"), (
-                f"policy_rows[{required!r}] has no decode_step_ms")
-        print("[policy rows] " + ", ".join(
-            f"{name}: {r['decode_step_ms']} ms/step, "
-            f"{r['packed_sites']}/{r['n_sites']} packed"
-            for name, r in rows.items()))
-
-    # paged continuous batching: required whenever the sweep covered the
-    # packed impl with real hif4 KV — the page-pool capacity claim (>= 2x
-    # admission at equal KV bytes, bitwise vs solo) must be recorded and
-    # passing, not silently dropped by a benchmark refactor
-    assert "paged_serve" in record, (
-        "BENCH_serve.json lacks `paged_serve` — serve_throughput must "
-        "record the paged-vs-slot scheduler comparison")
-    paged = record["paged_serve"]
-    if paged is None:
-        assert "hif4" not in packed_kvs, (
-            "BENCH_serve.json has `paged_serve` = null although the sweep "
-            "covered packed + hif4 KV — the paged comparison was skipped, "
-            "not inapplicable")
-        print("[paged serve] n/a (narrowed sweep)")
-    else:
-        assert paged.get("bitwise_vs_solo") is True, (
-            "paged_serve.bitwise_vs_solo is not true — paged scheduling "
-            "must be bit-identical to solo serving")
-        ratio = paged.get("admission_ratio")
-        assert ratio is not None and ratio >= 2.0, (
-            f"paged_serve.admission_ratio = {ratio!r} (gate: >= 2x the "
-            f"slot scheduler's concurrency at the same KV byte budget)")
-        assert not paged.get("kv_format_fallback"), (
-            "paged_serve ran on a fallen-back KV format — the pool is "
-            "HiF4-only, this row is mislabeled")
-        print(f"[paged serve] {paged['max_concurrent_paged']} vs "
-              f"{paged['max_concurrent_slot']} concurrent "
-              f"({ratio}x) at {paged['pool_bytes']} KV bytes, "
-              f"{paged['shared_page_hits']} shared-page hits, "
-              f"{paged['preemptions']} preemptions, bitwise vs solo")
 
 
 def main():
@@ -166,7 +24,7 @@ def main():
                     help="skip the (slow) tiny-LM accuracy proxy")
     args = ap.parse_args()
 
-    from benchmarks import dot_product, format_table, quant_error, roofline
+    from benchmarks import dot_product, format_table, quant_error
 
     sections = [
         ("format_table (Table I/II)", format_table.main),
@@ -174,18 +32,8 @@ def main():
         ("dot_product (§III.B / Fig. 4)", dot_product.main),
     ]
     if not args.skip_llm:
-        from benchmarks import llm_accuracy, serve_throughput
+        from benchmarks import llm_accuracy
         sections.append(("llm_accuracy (Tables III-V proxy)", llm_accuracy.main))
-        # LLM-class work too: init + prefill + decode of the reduced model
-        sections.append(
-            ("serve_throughput (deployment)", lambda: serve_throughput.main([]))
-        )
-    sections.append(("roofline (§Roofline)", roofline.main))
-
-    # the serve + matrix gates are checked even under --skip-llm (against
-    # the committed BENCH_*.json): a missing gate fails loudly, never skips
-    sections.append(("serve perf gates (BENCH_serve.json)", check_serve_gates))
-    sections.append(("matrix perf gates (BENCH_matrix.json)", check_matrix_gates))
 
     from tools import check_docs
     sections.append(("check_docs (repo lint)", check_docs.main))

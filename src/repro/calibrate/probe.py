@@ -19,7 +19,8 @@ the resolved :class:`~repro.core.policy.QuantPlan` enumerates:
   matching exactly what ``prepare_params_for_serving`` + the plan's
   ``packed_paths`` would make resident;
 * **roofline latency contribution**: site bytes / measured stream
-  bandwidth (``benchmarks/roofline.py``), when a bandwidth is supplied.
+  bandwidth (``repro.calibrate.run.measure_bandwidth``), when a
+  bandwidth is supplied.
 
 The probe is model-agnostic: site enumeration, packability and
 contraction axes all come from plan resolution, and activations come
@@ -176,8 +177,7 @@ def probe_sites(cfg: ArchConfig, *, params: Optional[dict] = None,
                 mem_bw: Optional[float] = None,
                 log=print) -> CalibrationResult:
     """Run the calibration pass and score every plan site (see module
-    docstring). ``params`` defaults to a seeded random init (the same
-    convention the scenario matrix serves)."""
+    docstring). ``params`` defaults to a seeded random init."""
     from repro.runtime.scenario import prefill_batch
 
     if params is None:

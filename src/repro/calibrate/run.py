@@ -1,9 +1,8 @@
 """End-to-end calibration: probe -> frontier search -> emitted policy.
 
 ``calibrate()`` is the library entry the CLI (``repro.launch.calibrate``)
-and the scenario matrix's searched-policy cell both drive. Beyond
-chaining the three layers it does the two pieces of bookkeeping that make
-the output trustworthy:
+drives. Beyond chaining the three layers it does the two pieces of
+bookkeeping that make the output trustworthy:
 
 * **baseline scoring** — the hand-written presets (``sensitive-fallback``,
   ``paper-iv``) are resolved against the same architecture and priced on
@@ -18,7 +17,11 @@ the output trustworthy:
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional
+
+import jax
+import jax.numpy as jnp
 
 from repro.calibrate.emit import emit_policy, emit_report
 from repro.calibrate.probe import DENSE_BPV, PACKED_BPV, probe_sites
@@ -30,15 +33,18 @@ from repro.models import lm
 BASELINE_PRESETS = ("sensitive-fallback", "paper-iv")
 
 
-def measure_bandwidth() -> Optional[float]:
-    """Stream bandwidth in bytes/s via benchmarks/roofline.py, or None
-    when the benchmarks package is not importable (it lives at the repo
-    root, outside the installed ``repro`` tree)."""
-    try:
-        from benchmarks.roofline import measure_stream_bandwidth
-    except ImportError:
-        return None
-    return float(measure_stream_bandwidth())
+def measure_bandwidth(nbytes: int = 1 << 27, repeats: int = 5) -> float:
+    """Stream bandwidth in bytes/s of this backend: best-of-N on a jitted
+    elementwise map over ``nbytes`` of f32 (reads and writes it once)."""
+    x = jnp.zeros(nbytes // 4, jnp.float32)
+    f = jax.jit(lambda a: a * 1.0000001 + 1.0)
+    jax.block_until_ready(f(x))                      # compile
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(f(x))
+        best = min(best, time.perf_counter() - t0)
+    return 2 * x.nbytes / best
 
 
 def _preset_assignment(cfg, preset: str, budget_paths) -> dict:
@@ -69,8 +75,7 @@ def calibrate(arch: str, *, reduced: bool = True, target_bpv=0.7,
     ``target_bpv`` is a float budget in bytes/value — or the name of a
     baseline preset (``sensitive-fallback``, ``paper-iv``), meaning
     "match that preset's measured byte residency on this architecture":
-    the Pareto comparison at equal bytes the matrix's
-    searched_policy_frontier gate records.
+    the Pareto comparison at equal bytes.
     """
     cfg = get_arch(arch)
     if reduced:

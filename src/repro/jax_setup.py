@@ -1,7 +1,7 @@
 """Process-wide JAX setup that entry points make before anything compiles.
 
 Entry points (``chip_smoke.py``, ``python -m repro``, the serve and train
-launchers, ``benchmarks.matrix``) call :func:`configure_jax` once, before
+launchers, ``bench/harness.py``) call :func:`configure_jax` once, before
 JAX starts a backend; library modules never do. It does two things.
 
 * **Compilation cache.** With ``JAX_COMPILATION_CACHE_DIR`` set, nothing

@@ -1,127 +1,26 @@
-"""Scenario-matrix harness: declarative serve cells with dispatch probes.
-
-A :class:`Scenario` is ONE cell of the regression matrix
-(arch x impl x kv_format x policy x batch x seqlen), declared as data:
-what to serve, how to serve it, which engine routes the cell MUST take
-(``expect``), and how much measured-latency drift the stored trajectory
-tolerates (``rel_tol``). :func:`run_scenarios` executes every cell
-through the real ``repro.runtime.serve_loop`` stack — the same jitted
-prefill / quantize-KV / decode-scan (or page-pool ``serve_requests``)
-path production serving runs — and returns one record per cell carrying:
-
-- measured steady-state decode-step latency (interleaved best-of-N on
-  the jitted scan: the cells alternate inside one timing loop so
-  sustained machine-load phases hit every cell equally — sequential
-  phases were measured to swing CPU ratios 2.5-4x) and prefill latency;
-- a roofline byte count built from EXACT HiF4 payload sizes (0.5625
-  B/value packed weights, ``kvcache.kv_bytes_per_token`` for the cache)
-  — ``benchmarks/roofline.py`` turns it into a predicted step time
-  against the measured stream bandwidth;
-- the engine dispatch actually probed for the cell
-  (:func:`repro.core.engine.attention_dispatch_info` /
-  :func:`packed_dispatch_info` / :func:`resolve_kv_format`) checked
-  against the declared ``expect`` assertions.
-
-Dispatch is probed analytically rather than spied at runtime because the
-serve jit cache (``serve_loop._JIT_CACHE``) means repeated cells never
-re-trace; ``tests/test_scenario.py`` pins probe == actual execution.
-
-``benchmarks/matrix.py`` declares the cells and owns the stored
-``BENCH_matrix.json`` trajectory + gates; this module is the mechanism.
+"""The probes of a serve cell: each family's prefill inputs, the analytic
+dispatch probe, and a decode step's exact byte count. The serve launcher
+and the calibrator take their prefill inputs from :func:`prefill_batch`;
+``chip_smoke.py`` and ``tests/test_kv_format_matrix.py`` check routes with
+:func:`probe_dispatch` (``tests/test_scenario.py`` pins it to the cache a
+cell actually serves, since the serve jit cache rules out spying on a
+run); ``bench/tests`` checks the benchmark's byte counts against
+:func:`decode_step_bytes`.
 """
-import dataclasses
-import os
-import shutil
-import tempfile
-import time
-from typing import Optional, Sequence
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs import get_arch
 from repro.core import engine as qengine
 from repro.core import kvcache
-from repro.core.policy import get_policy
 from repro.core.qlinear import PackedW
-from repro.models import lm
-from repro.models.common import ModelCtx
-from repro.runtime import faults
-from repro.runtime import serve_loop
 from repro.runtime.serve_loop import (
     ServeConfig,
-    build_decode_cache,
     kv_format_fallback,
     packed_weight_bytes,
     resolve_kv_format,
-    serve_requests,
 )
-
-
-@dataclasses.dataclass(frozen=True)
-class Scenario:
-    """One declarative cell of the serve regression matrix."""
-
-    name: str                     # unique cell id in BENCH_matrix.json
-    arch: str                     # registry id; served .reduced()
-    impl: str                     # qdq | packed | pallas
-    kv_format: str                # REQUESTED cache format: bf16 | hif4
-    paged: bool = False           # page-pool serve_requests cell
-    guarded: bool = False         # guarded decode scan + per-chunk KV audit
-    journaled: bool = False       # write-ahead journal + pool checkpoints
-    #                               (journal dir is a per-run tempdir)
-    recovery: bool = False        # crash (crash_mid_decode) + --resume cell:
-    #                               records the recovery report and asserts
-    #                               bitwise-identical recovered outputs
-    decode_chunk: int = 0         # tokens per jitted scan chunk (0 = budget);
-    #                               journal commits are per chunk, so the
-    #                               journal cells pin it for a fair ratio
-    policy: str = "uniform:hif4"  # QuantPolicy preset for weight sites
-    batch: int = 2
-    prompt_len: int = 16
-    new_tokens: int = 8
-    rel_tol: float = 3.0          # regression factor vs stored decode_step_ms
-    # expected-dispatch assertions, e.g. ("kv:hif4", "kv:no-fallback",
-    # "attn:fused_decode_attention", "matmul:fused") — see check_expect
-    expect: Sequence[str] = ()
-
-
-# expectation vocabulary -> how the probed dispatch must look. Routes are
-# backend-NEUTRAL: "attn:fused_decode_attention" means the cell is
-# kernel-eligible (the Pallas kernel on TPU, its bit-exact XLA twin
-# off-TPU); "attn:twin" means the chunked-dequantize twin is the ONLY
-# possible execution (qdq impl / layout), on every backend.
-_EXPECT_CHECKS = {
-    "kv:hif4": lambda d: d["kv_format_resolved"] == "hif4",
-    "kv:bf16": lambda d: d["kv_format_resolved"] == "bf16",
-    "kv:fallback": lambda d: d["kv_format_fallback"],
-    "kv:no-fallback": lambda d: not d["kv_format_fallback"],
-    "attn:fused_decode_attention":
-        lambda d: d["attn"].get("kernel_eligible") and not d["paged"],
-    "attn:fused_paged_decode_attention":
-        lambda d: d["attn"].get("kernel_eligible") and d["paged"],
-    "attn:twin":
-        lambda d: d["attn"]["route"] != "none"
-        and d["attn"].get("kernel_eligible") is False,
-    "attn:dense": lambda d: d["attn"]["route"] == "dense",
-    "attn:none": lambda d: d["attn"]["route"] == "none",
-    "matmul:fused": lambda d: d["matmul"]["route"] == "fused",
-    "matmul:dequant-dot": lambda d: d["matmul"]["route"] == "dequant-dot",
-    "matmul:qdq": lambda d: d["matmul"]["route"] == "qdq",
-}
-
-EXPECTATIONS = tuple(sorted(_EXPECT_CHECKS))
-
-
-def check_expect(expect: Sequence[str], dispatch: dict) -> list:
-    """The declared assertions a probed dispatch violates (empty = pass)."""
-    failed = []
-    for e in expect:
-        if e not in _EXPECT_CHECKS:
-            failed.append(f"{e} (unknown expectation)")
-        elif not _EXPECT_CHECKS[e](dispatch):
-            failed.append(e)
-    return failed
 
 
 def prefill_batch(cfg, batch: int, prompt_len: int, seed: int = 1) -> dict:
@@ -237,255 +136,3 @@ def decode_step_bytes(cfg, serving_params, cache, valid_len: int) -> dict:
         "state_bytes": state_bytes,
         "bytes_per_step": weight_bytes + kv_bytes + state_bytes,
     }
-
-
-def _build_cell(scn: Scenario):
-    """Materialize one cell: cfg/ctx/plan, serving params, decode state."""
-    cfg = get_arch(scn.arch).reduced()
-    plan = lm.quant_plan(cfg, get_policy(
-        scn.policy, impl=scn.impl, kv=kvcache.KVCacheConfig(scn.kv_format)))
-    ctx = ModelCtx(quant=plan.base, plan=plan, remat=False,
-                   attn_q_chunk=8, attn_k_chunk=8)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    sp = serve_loop.prepare_params_for_serving(params, cfg, plan)
-    return cfg, ctx, sp
-
-
-def _serve_cfg(scn: Scenario,
-               journal_dir: Optional[str] = None) -> ServeConfig:
-    sc = ServeConfig(max_new_tokens=scn.new_tokens, kv_format=scn.kv_format,
-                     decode_chunk=scn.decode_chunk)
-    if scn.paged:
-        # pool sized to hold every request at full length, page = 16 toks
-        pages = scn.batch * (
-            -(-(scn.prompt_len + scn.new_tokens) // 16)) + 1
-        sc = dataclasses.replace(sc, kv_pages=pages, kv_page_tokens=16,
-                                 cache_capacity=-(-(scn.prompt_len
-                                                    + scn.new_tokens) // 16) * 16)
-    if scn.journaled:
-        assert journal_dir is not None, (
-            f"cell {scn.name}: journaled Scenario needs a journal_dir")
-        # the overhead cell measures the WAL alone (fsync per chunk);
-        # pool checkpoints — whose cost is a cadence knob, absurdly dense
-        # at benchmark-cell scale (2-token chunks) — are exercised and
-        # timed by the recovery cell instead
-        sc = dataclasses.replace(sc, journal_dir=journal_dir,
-                                 checkpoint_every=2 if scn.recovery else 0)
-    return sc
-
-
-def run_scenarios(scenarios: Sequence[Scenario], *, repeats: int = 7,
-                  gate_pairs: Sequence[tuple] = (), log=print) -> list:
-    """Execute cells through the real serve stack; one record per cell.
-
-    Scan-served cells (everything non-paged) are timed INTERLEAVED on
-    their jitted decode scans, feeding each call's returned state back
-    (the scan donates its cache — this is exactly the serving steady
-    state), best-of-``repeats``. Paged cells run the page-pool
-    ``serve_requests`` scheduler end-to-end (admission + prefill +
-    decode), so their latency is a coarser ms/token — their ``rel_tol``
-    should say so. Each record's ``roofline`` carries exact payload byte
-    counts; ``benchmarks.roofline`` turns them into predicted times.
-
-    ``gate_pairs`` lists (baseline, subject) cell-name pairs the ratio
-    gates compare. Each pair gets a SECOND, tight A/B interleave after
-    the global rotation, recorded on the subject's record under
-    ``gate_timing``: inside the full rotation every step inherits a
-    different predecessor's cache/allocator state, and on CPU hosts
-    that churn swings a single cell 10-20% between runs — noise a
-    per-cell rel_tol absorbs but a two-cell ratio does not. Strict
-    alternation gives both sides the same predecessor (each other), the
-    same reasoning that made serve_throughput's kv_format sweep
-    interleaved.
-    """
-    names = [s.name for s in scenarios]
-    assert len(set(names)) == len(names), f"duplicate cell names: {names}"
-    records, states, steps, serving, paged_cells = {}, {}, {}, {}, []
-    tmp_dirs = []
-    for scn in scenarios:
-        t_setup = time.perf_counter()
-        cfg, ctx, sp = _build_cell(scn)
-        jdir = None
-        if scn.journaled:
-            # tmpfs when available: the overhead gate measures the WAL's
-            # software cost (framing, fsync-batching, replay bookkeeping),
-            # not the sync latency of whatever disk backs $TMPDIR.
-            shm = "/dev/shm" if os.path.isdir("/dev/shm") else None
-            jdir = tempfile.mkdtemp(prefix=f"matrix_{scn.name}_", dir=shm)
-            tmp_dirs.append(jdir)
-        sc = _serve_cfg(scn, journal_dir=jdir)
-        dispatch = probe_dispatch(cfg, ctx.quant, sc, sp, paged=scn.paged,
-                                  batch=scn.batch, prompt_len=scn.prompt_len)
-        failed = check_expect(scn.expect, dispatch)
-        rec = dict(dataclasses.asdict(scn))
-        rec["expect"] = list(scn.expect)
-        rec.update({
-            "family": cfg.family,
-            "kv_format_resolved": dispatch["kv_format_resolved"],
-            "dispatch": {
-                "kv_format_fallback": dispatch["kv_format_fallback"],
-                "attn": dispatch["attn"],
-                "matmul": dispatch["matmul"],
-            },
-            "dispatch_ok": not failed,
-            "dispatch_failures": failed,
-        })
-        records[scn.name] = rec
-        if scn.paged:
-            paged_cells.append((scn, cfg, ctx, sp, sc))
-            log(f"[matrix] {scn.name}: paged cell set up "
-                f"({time.perf_counter() - t_setup:.1f}s)")
-            continue
-
-        sctx = serve_loop.serving_ctx(ctx)
-        batch = prefill_batch(cfg, scn.batch, scn.prompt_len)
-        prefill = serve_loop._jit_prefill(cfg, sctx)
-        if scn.guarded:
-            # production guarded chunk: one jitted call returns tokens +
-            # a fused flags vector (NaN sentinels ++ 0xFF meta counters),
-            # and the scheduler pulls tokens and flags to host in a single
-            # device_get after every chunk — those costs belong in the
-            # number the guard_overhead gate compares against the
-            # unguarded twin
-            gstep = serve_loop._jit_decode_scan_guarded(
-                cfg, sctx, scn.new_tokens, None)
-            zeros = jnp.zeros((scn.batch,), bool)
-
-            def step(sp, token, cache, done, _g=gstep, _z=zeros):
-                toks, token, cache, done, flags = _g(sp, token, cache,
-                                                     done, _z)
-                jax.device_get((toks, flags))
-                return toks, token, cache, done
-        else:
-            ustep = serve_loop._jit_decode_scan(cfg, sctx, scn.new_tokens,
-                                                None)
-
-            # schedulers pull tokens to host every chunk; time that too so
-            # guarded and unguarded cells differ only by the guard work
-            def step(sp, token, cache, done, _u=ustep):
-                toks, token, cache, done = _u(sp, token, cache, done)
-                jax.device_get(toks)
-                return toks, token, cache, done
-        logits, cache = build_decode_cache(cfg, sp, batch, sctx, sc,
-                                           quant=ctx.quant)
-        rec["roofline"] = decode_step_bytes(
-            cfg, sp, cache, scn.prompt_len + scn.new_tokens // 2)
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        done = jnp.zeros(token.shape, bool)
-        toks, token, cache, done = step(sp, token, cache, done)
-        jax.block_until_ready(toks)                  # compile + warmup
-        t_pre = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            out = prefill(sp, batch)
-            jax.block_until_ready(out)
-            t_pre = min(t_pre, time.perf_counter() - t0)
-        rec["prefill_ms"] = round(t_pre * 1e3, 4)
-        serving[scn.name], steps[scn.name] = sp, step
-        states[scn.name] = (token, cache, done)
-        log(f"[matrix] {scn.name}: compiled + warm "
-            f"({time.perf_counter() - t_setup:.1f}s)")
-
-    # interleaved steady-state decode timing across ALL scan cells
-    best = {name: float("inf") for name in states}
-    for _ in range(repeats):
-        for name in states:
-            token, cache, done = states[name]
-            t0 = time.perf_counter()
-            toks, token, cache, done = steps[name](
-                serving[name], token, cache, done)
-            jax.block_until_ready(toks)
-            n = records[name]["new_tokens"]
-            best[name] = min(best[name], (time.perf_counter() - t0) / n)
-            states[name] = (token, cache, done)
-    for name, t in best.items():
-        records[name]["decode_step_ms"] = round(t * 1e3, 4)
-        records[name]["timing"] = "scan-interleaved"
-
-    # tight pairwise A/B interleave per ratio-gate pair (see docstring)
-    for base_name, sub_name in gate_pairs:
-        if base_name not in states or sub_name not in states:
-            continue
-        pair_best = {base_name: float("inf"), sub_name: float("inf")}
-        for _ in range(3 * repeats):
-            for name in (base_name, sub_name):
-                token, cache, done = states[name]
-                t0 = time.perf_counter()
-                toks, token, cache, done = steps[name](
-                    serving[name], token, cache, done)
-                jax.block_until_ready(toks)
-                n = records[name]["new_tokens"]
-                pair_best[name] = min(pair_best[name],
-                                      (time.perf_counter() - t0) / n)
-                states[name] = (token, cache, done)
-        records[sub_name].setdefault("gate_timing", {})[base_name] = {
-            "baseline_ms": round(pair_best[base_name] * 1e3, 4),
-            "subject_ms": round(pair_best[sub_name] * 1e3, 4)}
-
-    pmap = {scn.name: (scn, cfg, ctx, sp, sc,
-                       [jax.random.randint(jax.random.PRNGKey(40 + i),
-                                           (scn.prompt_len,), 0, cfg.vocab)
-                        for i in range(scn.batch)])
-            for scn, cfg, ctx, sp, sc in paged_cells}
-
-    def paged_e2e(name, *, stats=None, injector=None, resume=False):
-        scn, cfg, ctx, sp, sc, reqs = pmap[name]
-        t0 = time.perf_counter()
-        out = serve_requests(cfg, sp, reqs, ctx, sc, slots=scn.batch,
-                             stats=stats, injector=injector, resume=resume)
-        jax.block_until_ready(out)
-        return out, time.perf_counter() - t0
-
-    rounds = max(2, repeats // 3)
-    for name, (scn, cfg, ctx, sp, sc, reqs) in pmap.items():
-        rec = records[name]
-        t_e2e, out = float("inf"), None
-        for _ in range(rounds):
-            out, dt = paged_e2e(name)
-            t_e2e = min(t_e2e, dt)
-        rec["decode_step_ms"] = round(t_e2e / scn.new_tokens * 1e3, 4)
-        rec["timing"] = "e2e-paged"
-        rec["prefill_ms"] = None
-        cache = lm.init_cache(cfg, scn.batch, scn.prompt_len + scn.new_tokens,
-                              "hif4")
-        rec["roofline"] = decode_step_bytes(
-            cfg, sp, cache, scn.prompt_len + scn.new_tokens // 2)
-        log(f"[matrix] {scn.name}: paged e2e {rec['decode_step_ms']} ms/tok")
-        if scn.recovery:
-            # crash the journaled serve mid-decode, then resume from its
-            # journal and require bitwise-identical recovered outputs
-            ref = [jax.device_get(r).tolist() for r in out]
-            inj = faults.FaultInjector(faults.FaultSpec(
-                "crash_mid_decode", after_chunk=1))
-            crashed = False
-            try:
-                paged_e2e(name, injector=inj)
-            except faults.SimulatedCrash:
-                crashed = True
-            stats: dict = {}
-            out2, dt2 = paged_e2e(name, stats=stats, resume=True)
-            got = [jax.device_get(r).tolist() for r in out2]
-            rec["recovery"] = dict(
-                stats.get("recovery", {}), crashed=crashed,
-                bitwise=(got == ref), resume_ms=round(dt2 * 1e3, 3))
-            log(f"[matrix] {scn.name}: recovery {rec['recovery']}")
-
-    # tight pairwise A/B interleave for paged gate pairs (the scan-cell
-    # loop above covers pairs timed on jitted decode scans; paged cells
-    # are timed end-to-end, so their ratio gets the same treatment here)
-    for base_name, sub_name in gate_pairs:
-        if base_name not in pmap or sub_name not in pmap:
-            continue
-        pair_best = {base_name: float("inf"), sub_name: float("inf")}
-        for _ in range(2 * rounds):
-            for name in (base_name, sub_name):
-                _, dt = paged_e2e(name)
-                pair_best[name] = min(pair_best[name],
-                                      dt / pmap[name][0].new_tokens)
-        records[sub_name].setdefault("gate_timing", {})[base_name] = {
-            "baseline_ms": round(pair_best[base_name] * 1e3, 4),
-            "subject_ms": round(pair_best[sub_name] * 1e3, 4)}
-
-    for d in tmp_dirs:
-        shutil.rmtree(d, ignore_errors=True)
-    return [records[s.name] for s in scenarios]

@@ -21,19 +21,6 @@ def test_lint_catches_dead_references(tmp_path):
     assert len(errors) == 2
 
 
-def test_lint_checks_matrix_gate_names(tmp_path):
-    """Documented gates must exist in benchmarks.matrix.GATE_NAMES — a
-    doc claiming a gate check_matrix_gates does not enforce fails."""
-    ok = tmp_path / "ok.md"
-    ok.write_text("enforced as gate:`dispatch_ok` and "
-                  "gate:`trajectory_regression`\n")
-    assert check_docs.check_file(str(ok)) == []
-    bad = tmp_path / "bad.md"
-    bad.write_text("enforced as gate:`no_such_gate`\n")
-    errors = check_docs.check_file(str(bad))
-    assert len(errors) == 1 and "no_such_gate" in errors[0]
-
-
 def test_lint_checks_fault_class_names(tmp_path):
     """Documented fault/crash classes must exist in
     repro.runtime.faults.FAULT_CLASSES — a recovery matrix naming a

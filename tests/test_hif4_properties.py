@@ -1,7 +1,7 @@
 """Hypothesis property tests for repro.core.hif4 round-trip invariants.
 
 Randomized shapes / magnitudes / group boundaries pin the properties the
-scenario matrix and the packed serving stack rest on: exact power-of-two
+packed serving stack rests on: exact power-of-two
 group scales (scale equivariance), 0xFF-metadata NaN propagation through
 EVERY decode path, bit-level pack/unpack idempotence, and bulk-pack ==
 token-at-a-time append for the KV cache. Deterministic ci profile, same

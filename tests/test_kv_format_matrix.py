@@ -1,4 +1,4 @@
-"""resolve_kv_format fallback loudness as a full family matrix.
+"""The family matrix: KV-format fallback loudness and the dispatch table.
 
 Every registry arch x every requested KV format, asserting that the
 ``kv_format_fallback`` flag agrees with (a) the ``KVFallbackWarning``
@@ -8,7 +8,13 @@ exact sequence ``serve`` decodes against. The enc-dec families
 (audio/vlm) must serve packed HiF4 — including the whisper cross
 (encoder) cache — with no fallback; only the SSM-state families
 (ssm/hybrid) may narrow, and must say so.
+
+The dispatch table pins, per family x impl x KV format x policy, which
+attention route and which matmul route ``scenario.probe_dispatch``
+resolves; ``tests/test_scenario.py`` pins the probe to what a cell
+actually serves.
 """
+import functools
 import warnings
 
 import jax
@@ -17,11 +23,12 @@ import pytest
 
 from repro.configs import get_arch
 from repro.core import kvcache
+from repro.core.policy import get_policy
 from repro.core.qlinear import QuantConfig
 from repro.models import lm
 from repro.models.common import ModelCtx
 from repro.runtime import serve_loop
-from repro.runtime.scenario import prefill_batch
+from repro.runtime.scenario import prefill_batch, probe_dispatch
 from repro.runtime.serve_loop import (
     KVFallbackWarning,
     ServeConfig,
@@ -85,3 +92,99 @@ def test_fallback_flag_agrees_with_served_cache(arch, requested):
         # permanent-fallback cell is gone
         assert fmts["cross"] == resolved
         assert set(fmts) == {"self", "cross"}
+
+
+# short name -> registry arch, in ARCHS order
+SHORT = dict(zip(("qwen", "moe", "mamba", "hybrid", "whisper", "llava"),
+                 ARCHS))
+
+# (arch, impl, requested kv, policy, paged) -> (attn route, matmul route).
+# Routes are backend-neutral: "fused_decode_attention" means the cache is
+# kernel-eligible (the Pallas kernel on TPU, its bitwise twin off-TPU),
+# "twin" means the chunked-dequantize twin is the only execution. qdq
+# always takes the twin; so does the reduced vlm arch, whose 1 kv-head x
+# 32 d_head is below one 64-value HiF4 group (a tail-only cache). ssm has
+# no attention; hybrid falls back to a bf16 cache and its doubly-stacked
+# blocks never pack.
+DISPATCH = (
+    ("qwen", "qdq", "hif4", "uniform:hif4", False, "twin", "qdq"),
+    ("qwen", "packed", "hif4", "uniform:hif4", False,
+     "fused_decode_attention", "fused"),
+    ("qwen", "pallas", "hif4", "uniform:hif4", False,
+     "fused_decode_attention", "fused"),
+    ("moe", "qdq", "hif4", "uniform:hif4", False, "twin", "qdq"),
+    ("moe", "packed", "hif4", "uniform:hif4", False,
+     "fused_decode_attention", "fused"),
+    ("moe", "pallas", "hif4", "uniform:hif4", False,
+     "fused_decode_attention", "fused"),
+    ("mamba", "qdq", "hif4", "uniform:hif4", False, "none", "qdq"),
+    ("mamba", "packed", "hif4", "uniform:hif4", False, "none", "fused"),
+    ("mamba", "pallas", "hif4", "uniform:hif4", False, "none", "fused"),
+    ("hybrid", "qdq", "hif4", "uniform:hif4", False, "dense", "qdq"),
+    ("hybrid", "packed", "hif4", "uniform:hif4", False, "dense", "qdq"),
+    ("hybrid", "pallas", "hif4", "uniform:hif4", False, "dense", "qdq"),
+    ("whisper", "qdq", "hif4", "uniform:hif4", False, "twin", "qdq"),
+    ("whisper", "packed", "hif4", "uniform:hif4", False,
+     "fused_decode_attention", "fused"),
+    ("whisper", "pallas", "hif4", "uniform:hif4", False,
+     "fused_decode_attention", "fused"),
+    ("llava", "qdq", "hif4", "uniform:hif4", False, "twin", "qdq"),
+    ("llava", "packed", "hif4", "uniform:hif4", False, "twin", "fused"),
+    ("llava", "pallas", "hif4", "uniform:hif4", False, "twin", "fused"),
+    ("qwen", "packed", "bf16", "uniform:hif4", False, "dense", "fused"),
+    ("moe", "packed", "bf16", "uniform:hif4", False, "dense", "fused"),
+    ("mamba", "packed", "bf16", "uniform:hif4", False, "none", "fused"),
+    ("hybrid", "packed", "bf16", "uniform:hif4", False, "dense", "qdq"),
+    ("whisper", "packed", "bf16", "uniform:hif4", False, "dense", "fused"),
+    ("llava", "packed", "bf16", "uniform:hif4", False, "dense", "fused"),
+    ("qwen", "qdq", "bf16", "uniform:hif4", False, "dense", "qdq"),
+    ("qwen", "packed", "hif4", "paper-iv", False,
+     "fused_decode_attention", "fused"),
+    ("qwen", "packed", "hif4", "sensitive-fallback", False,
+     "fused_decode_attention", "fused"),
+    ("moe", "packed", "hif4", "paper-iv", False,
+     "fused_decode_attention", "fused"),
+    ("moe", "packed", "hif4", "sensitive-fallback", False,
+     "fused_decode_attention", "fused"),
+    ("qwen", "packed", "hif4", "uniform:hif4", True,
+     "fused_paged_decode_attention", "fused"),
+    ("moe", "packed", "hif4", "uniform:hif4", True,
+     "fused_paged_decode_attention", "fused"),
+)
+
+
+def _case_id(case):
+    short, impl, kv, policy, paged = case[:5]
+    tail = "" if policy == "uniform:hif4" else f"-{policy}"
+    return f"{short}-{impl}-{kv}{tail}{'-paged' if paged else ''}"
+
+
+@functools.lru_cache(maxsize=None)
+def _serving_params(arch, impl, policy):
+    cfg = get_arch(arch).reduced()
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    plan = lm.quant_plan(cfg, get_policy(policy, impl=impl))
+    return serve_loop.prepare_params_for_serving(params, cfg, plan)
+
+
+def _attn_route(d):
+    a = d["attn"]
+    if a["route"] in ("none", "dense"):
+        return a["route"]
+    if not a["kernel_eligible"]:
+        return "twin"
+    return ("fused_paged_decode_attention" if d["paged"]
+            else "fused_decode_attention")
+
+
+@pytest.mark.parametrize("case", DISPATCH, ids=_case_id)
+def test_dispatch_routes_per_family(case):
+    short, impl, kv, policy, paged, attn, matmul = case
+    cfg = get_arch(SHORT[short]).reduced()
+    plan = lm.quant_plan(cfg, get_policy(
+        policy, impl=impl, kv=kvcache.KVCacheConfig(kv)))
+    sc = ServeConfig(max_new_tokens=8, kv_format=kv, kv_page_tokens=16)
+    d = probe_dispatch(cfg, plan.base, sc,
+                       _serving_params(SHORT[short], impl, policy),
+                       paged=paged, batch=2, prompt_len=16)
+    assert (_attn_route(d), d["matmul"]["route"]) == (attn, matmul), d

@@ -1,76 +1,43 @@
-"""The scenario harness executes real serve cells and probes honestly.
+"""The analytic dispatch probe agrees with what a cell actually serves.
 
-Runs two tiny cells (dense hif4 + audio hif4) through
-``run_scenarios`` and pins (a) the record schema the matrix gates
-consume, (b) that the analytic dispatch probe agrees with
-``engine.attention_dispatch_info`` evaluated on the cache leaves the
-cell ACTUALLY served (built via ``build_decode_cache``) — the probe is
-trusted because the serve jit cache prevents runtime spying, so this
-equivalence is the load-bearing test.
+Builds two tiny cells (dense hif4 + audio hif4) and checks that
+``scenario.probe_dispatch`` agrees with ``engine.attention_dispatch_info``
+evaluated on the cache leaves the cell ACTUALLY served (built via
+``build_decode_cache``). The probe is trusted because the serve jit cache
+prevents runtime spying, so this equivalence is the load-bearing test
+behind the dispatch table in ``tests/test_kv_format_matrix.py``.
 """
-import os
-import sys
-
 import jax
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
+from repro.configs import get_arch
 from repro.core import engine as qengine
+from repro.core import kvcache
+from repro.core.policy import get_policy
+from repro.models import lm
+from repro.models.common import ModelCtx
 from repro.runtime import scenario, serve_loop
-from repro.runtime.scenario import Scenario, run_scenarios
 
-CELLS = (
-    Scenario(name="dense-hif4", arch="qwen1.5-0.5b", impl="packed",
-             kv_format="hif4", batch=1, prompt_len=8, new_tokens=4,
-             expect=("kv:hif4", "kv:no-fallback",
-                     "attn:fused_decode_attention", "matmul:fused")),
-    Scenario(name="audio-hif4", arch="whisper-tiny", impl="packed",
-             kv_format="hif4", batch=1, prompt_len=8, new_tokens=4,
-             expect=("kv:hif4", "kv:no-fallback",
-                     "attn:fused_decode_attention", "matmul:fused")),
-)
-
-
-@pytest.fixture(scope="module")
-def records():
-    return {r["name"]: r for r in run_scenarios(CELLS, repeats=2,
-                                                log=lambda *_: None)}
+CELLS = {"dense-hif4": "qwen1.5-0.5b", "audio-hif4": "whisper-tiny"}
 
 
 @pytest.mark.slow
-def test_record_schema_and_measurements(records):
-    assert set(records) == {"dense-hif4", "audio-hif4"}
-    for rec in records.values():
-        assert rec["dispatch_ok"] is True, rec["dispatch_failures"]
-        assert rec["dispatch_failures"] == []
-        assert rec["decode_step_ms"] > 0
-        assert rec["prefill_ms"] > 0
-        assert rec["timing"] == "scan-interleaved"
-        assert rec["kv_format_resolved"] == "hif4"
-        assert rec["dispatch"]["kv_format_fallback"] is False
-        ro = rec["roofline"]
-        assert ro["bytes_per_step"] == (ro["weight_bytes"] + ro["kv_bytes"]
-                                        + ro["state_bytes"])
-        assert ro["weight_bytes"] > 0 and ro["kv_bytes"] > 0
-    # whisper decodes against self + cross caches; qwen against one kv
-    assert (records["audio-hif4"]["roofline"]["kv_bytes"]
-            != records["dense-hif4"]["roofline"]["kv_bytes"])
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("scn", CELLS, ids=lambda s: s.name)
-def test_probe_agrees_with_served_cache(scn):
+@pytest.mark.parametrize("arch", CELLS.values(), ids=CELLS.keys())
+def test_probe_agrees_with_served_cache(arch):
     """probe_dispatch == attention_dispatch_info on the served leaves."""
-    cfg, ctx, sp = scenario._build_cell(scn)
-    sc = scenario._serve_cfg(scn)
-    probe = scenario.probe_dispatch(cfg, ctx.quant, sc, sp,
-                                    batch=scn.batch,
-                                    prompt_len=scn.prompt_len)
-    sctx = serve_loop.serving_ctx(ctx)
+    cfg = get_arch(arch).reduced()
+    plan = lm.quant_plan(cfg, get_policy(
+        "uniform:hif4", impl="packed", kv=kvcache.KVCacheConfig("hif4")))
+    ctx = ModelCtx(quant=plan.base, plan=plan, remat=False,
+                   attn_q_chunk=8, attn_k_chunk=8)
+    sp = serve_loop.prepare_params_for_serving(
+        lm.init_params(cfg, jax.random.PRNGKey(0)), cfg, plan)
+    sc = serve_loop.ServeConfig(max_new_tokens=4, kv_format="hif4")
+    probe = scenario.probe_dispatch(cfg, ctx.quant, sc, sp, batch=1,
+                                    prompt_len=8)
     _, cache = serve_loop.build_decode_cache(
-        cfg, sp, scenario.prefill_batch(cfg, scn.batch, scn.prompt_len),
-        sctx, sc, quant=ctx.quant)
+        cfg, sp, scenario.prefill_batch(cfg, 1, 8),
+        serve_loop.serving_ctx(ctx), sc, quant=ctx.quant)
     entry = cache["self"] if cfg.family == "audio" else cache["kv"]
     a = cfg.attn
     actual = qengine.attention_dispatch_info(

@@ -10,17 +10,13 @@ whose prose makes cross-module claims about layouts and test anchors) for
     importable module prefix is imported and the remainder resolved with
     getattr — a renamed function or deleted module fails the lint;
   * repo-relative file references (``docs/FORMATS.md``,
-    ``benchmarks/serve_throughput.py``, ``tests/test_engine.py``, ...):
+    ``benchmarks/run.py``, ``tests/test_engine.py``, ...):
     the path must exist;
   * quantization-policy preset references (``--policy paper-iv``,
     backticked ``uniform:<fmt>`` spellings, and backticked hyphenated
-    names on lines that mention a policy/preset, scenario-matrix cell
-    names excepted): the name must resolve in the ``repro.core.policy``
-    preset registry — docs advertising a renamed or deleted preset fail
-    CI;
-  * matrix perf-gate references (the ``gate:`name``` spelling): the name
-    must be declared in ``benchmarks.matrix.GATE_NAMES`` — docs
-    documenting a gate ``check_matrix_gates`` does not enforce fail CI;
+    names on lines that mention a policy/preset): the name must resolve
+    in the ``repro.core.policy`` preset registry — docs advertising a
+    renamed or deleted preset fail CI;
   * serve-status references (the ``status:`name``` spelling): the name
     must be declared in ``repro.runtime.guard.STATUS_NAMES`` — the
     failure-semantics docs promise per-request terminal statuses, and a
@@ -63,10 +59,6 @@ PATH_RE = re.compile(
 POLICY_FLAG_RE = re.compile(r"--policy[ =]+([A-Za-z0-9_:.\-/]+)")
 POLICY_UNIFORM_RE = re.compile(r"`(uniform:[A-Za-z0-9_]+)`")
 POLICY_NAME_RE = re.compile(r"`([a-z0-9]+(?:-[a-z0-9]+)+)`")
-
-# matrix perf-gate references: docs spell them gate:`name` so the lint
-# can tell a gate claim from ordinary backticked code
-GATE_RE = re.compile(r"gate:`([A-Za-z0-9_]+)`")
 
 # per-request serve statuses: docs spell them status:`name` so the
 # failure-semantics vocabulary stays pinned to the scheduler's enum
@@ -167,25 +159,13 @@ def check_file(path: str, docstring_only: bool = False) -> list[str]:
     for ref in sorted(set(PATH_RE.findall(text))):
         if not os.path.exists(os.path.join(REPO, ref)):
             errors.append(f"{rel}: dead file reference `{ref}`")
-    from benchmarks.matrix import CELLS
     from repro.core.policy import known_policy_spec
 
-    # a scenario-matrix cell name on a policy line names a cell, not a preset
-    cell_names = {c.name for c in CELLS}
-    for name in sorted(_policy_candidates(text) - cell_names):
+    for name in sorted(_policy_candidates(text)):
         if not known_policy_spec(name):
             errors.append(
                 f"{rel}: unknown policy preset `{name}` (not in the "
                 f"repro.core.policy registry)")
-    gate_refs = sorted(set(GATE_RE.findall(text)))
-    if gate_refs:
-        from benchmarks.matrix import GATE_NAMES
-
-        for name in gate_refs:
-            if name not in GATE_NAMES:
-                errors.append(
-                    f"{rel}: unknown matrix gate gate:`{name}` (not in "
-                    f"benchmarks.matrix.GATE_NAMES)")
     status_refs = sorted(set(STATUS_RE.findall(text)))
     if status_refs:
         from repro.runtime.guard import STATUS_NAMES
@@ -209,7 +189,6 @@ def check_file(path: str, docstring_only: bool = False) -> list[str]:
 
 def run() -> list[str]:
     sys.path.insert(0, os.path.join(REPO, "src"))
-    sys.path.insert(0, REPO)                   # for benchmarks.matrix
     errors = []
     for path in _doc_paths():
         errors.extend(check_file(path))
